@@ -13,19 +13,22 @@ reported):
 * ``islands`` — the 1-device island mesh vs the plain scan: gates on
   BIT-IDENTICAL outputs (the shard_map wrapper must be free when there
   is nothing to shard), and reports single-device evals/sec.
-* ``islands_multi`` — a subprocess with ``XLA_FLAGS=
-  --xla_force_host_platform_device_count=N`` runs the same search
-  sharded over N islands; reports evals/sec vs the 1-device arm.  On a
-  CPU host the forced devices share the same cores, so the gate is
-  sanity (the sharded dispatch completes and clears a floor), not
-  linear speedup.
-* ``pareto_kernel`` — the Pallas dominance-count kernel (interpret
-  mode off-TPU) vs the fused-jnp oracle on randomized populations with
-  injected duplicate rows: gates on exact count equality.
+* ``islands_multi`` — the same search sharded over N islands; reports
+  evals/sec vs the 1-device arm and names the platform.  Where several
+  devices exist it runs in this process on them (a child could not
+  reach an accelerator this process holds); otherwise a child pinned to
+  ``JAX_PLATFORMS=cpu`` forces N host devices, which share the same
+  cores, so the gate is sanity (the sharded dispatch completes and
+  clears a floor), not linear speedup.
+* ``pareto_kernel`` — the Pallas dominance-count kernel (compiled on a
+  TPU, interpret mode on the CPU) vs the fused-jnp oracle on randomized
+  populations with injected duplicate rows: gates on exact count
+  equality.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import os
 import subprocess
 import sys
@@ -48,7 +51,8 @@ from .common import QUICK
 
 OBJECTIVES = ("latency_ns", "cost_usd")
 SPACE_KW = dict(max_shape=(16, 16, 4, 4, 1, 2))
-SRC = str(Path(__file__).resolve().parents[1] / "src")
+ROOT = Path(__file__).resolve().parents[1]
+SRC = str(ROOT / "src")
 
 
 def _problem(name):
@@ -118,47 +122,57 @@ def _island_arm(cfg, repeat):
     return dict(evals_per_s=cfg.pop * cfg.generations / t)
 
 
+def sharded_evals_per_s(cfg, devices, repeat):
+    """Best-of-``repeat`` evals/sec of the scan sharded as one island per
+    device of ``devices``."""
+    spec, space = _problem("att2")
+    mesh = jax.sharding.Mesh(np.array(devices), (ISLAND_AXIS,))
+    cfg = dataclasses.replace(cfg, migration_interval=2)
+    pop0 = _pop0(space, cfg.pop, jax.random.PRNGKey(1))
+    run = make_nsga(spec, space, OBJECTIVES, cfg, mesh=mesh)
+    t = _time_dispatches(lambda i: run(jax.random.PRNGKey(i), pop0), repeat)
+    return cfg.pop * cfg.generations / t
+
+
 def _multi_island_arm(cfg, n_dev, repeat):
-    """Evals/sec of the sharded scan in a subprocess with ``n_dev``
-    forced host devices."""
+    """Evals/sec of the scan sharded over several devices: in this process
+    over the real devices when more than one exists (one process holds an
+    accelerator), else in a child pinned to the CPU with ``n_dev`` forced
+    host devices."""
+    devs = jax.devices()
+    if len(devs) > 1:
+        n = min(n_dev, len(devs))
+        return dict(evals_per_s=sharded_evals_per_s(cfg, devs[:n], repeat),
+                    n_dev=n, platform=devs[0].platform)
     prog = textwrap.dedent(f"""
-        import time
-        import numpy as np, jax
-        import repro.core as C
-        from repro.core.encoding import random_design
-        from repro.explore.nsga import ISLAND_AXIS, NSGAConfig, make_nsga
-        g = C.presets.bert_mms()["att2"]
-        spec = C.SystemSpec.build(g, ch_max=2)
-        space = C.DesignSpace(spec, max_shape={SPACE_KW['max_shape']!r})
+        import jax
+        from benchmarks.bench_scale import sharded_evals_per_s
+        from repro.explore.nsga import NSGAConfig
         assert len(jax.devices()) == {n_dev}
-        mesh = jax.sharding.Mesh(np.array(jax.devices()), (ISLAND_AXIS,))
-        cfg = NSGAConfig(pop={cfg.pop}, generations={cfg.generations},
-                         migration_interval=2)
-        pop0 = jax.vmap(lambda k: random_design(k, space))(
-            jax.random.split(jax.random.PRNGKey(1), cfg.pop))
-        run = make_nsga(spec, space, {OBJECTIVES!r}, cfg, mesh=mesh)
-        jax.block_until_ready(run(jax.random.PRNGKey(0), pop0))
-        best = float("inf")
-        for i in range({repeat}):
-            t0 = time.perf_counter()
-            jax.block_until_ready(run(jax.random.PRNGKey(i + 1), pop0))
-            best = min(best, time.perf_counter() - t0)
-        print("EVALS_PER_S", cfg.pop * cfg.generations / best)
+        cfg = NSGAConfig(pop={cfg.pop}, generations={cfg.generations})
+        print("EVALS_PER_S",
+              sharded_evals_per_s(cfg, jax.devices(), {repeat}))
     """)
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         f" --xla_force_host_platform_device_count={n_dev}")
-    env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
+    env["PYTHONPATH"] = os.pathsep.join(
+        filter(None, [SRC, str(ROOT), env.get("PYTHONPATH")]))
     r = subprocess.run([sys.executable, "-c", prog], env=env,
                        capture_output=True, text=True, timeout=900)
     assert r.returncode == 0, r.stderr[-2000:]
     eps = float(r.stdout.split("EVALS_PER_S")[1].strip().split()[0])
     assert eps > 0
-    return dict(evals_per_s=eps, n_dev=n_dev)
+    return dict(evals_per_s=eps, n_dev=n_dev, platform="cpu")
 
 
 def _pareto_kernel_arm(n, k, repeat):
-    os.environ["REPRO_PALLAS_INTERPRET"] = "1"
+    """Kernel vs oracle parity and timing: compiled on a TPU, in
+    interpret mode on the CPU."""
+    interpret = jax.default_backend() == "cpu"
+    if interpret:
+        os.environ["REPRO_PALLAS_INTERPRET"] = "1"
     try:
         from repro.kernels.pareto_rank.ops import dominance_counts
         ks = jax.random.split(jax.random.PRNGKey(17), 2)
@@ -174,9 +188,11 @@ def _pareto_kernel_arm(n, k, repeat):
             t0 = time.perf_counter()
             jax.block_until_ready(dominance_counts(objs, valid))
             best = min(best, time.perf_counter() - t0)
-        return dict(us=best * 1e6, n=n)
+        return dict(us=best * 1e6, n=n,
+                    mode="interpret" if interpret else "compiled")
     finally:
-        os.environ.pop("REPRO_PALLAS_INTERPRET", None)
+        if interpret:
+            os.environ.pop("REPRO_PALLAS_INTERPRET", None)
 
 
 def run(quick: bool = QUICK):
@@ -201,12 +217,13 @@ def run(quick: bool = QUICK):
              us_per_call=1e6 * cfg.pop * cfg.generations
              / one["evals_per_s"],
              derived="bit_identical=1"),
-        dict(name=f"scale_islands_{n_dev}dev",
+        dict(name=f"scale_islands_{multi['n_dev']}dev",
              us_per_call=1e6 * cfg.pop * cfg.generations
              / multi["evals_per_s"],
-             derived=f"scaling_vs_1dev={scaling:.2f}"),
+             derived=f"platform={multi['platform']};"
+                     f"scaling_vs_1dev={scaling:.2f}"),
         dict(name="scale_pareto_kernel", us_per_call=pk["us"],
-             derived=f"n={pk['n']};parity=1"),
+             derived=f"n={pk['n']};mode={pk['mode']};parity=1"),
     ]
 
 

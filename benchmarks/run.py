@@ -9,6 +9,8 @@ REPRO_BENCH_FULL=1 for the bigger search budgets) to recompute.
 
 import sys
 
+from repro.compile_cache import enable as enable_compile_cache
+
 from . import (bench_validation, bench_cost_fig3, bench_comparison,
                bench_codesign, bench_pareto, bench_explore, bench_transfer,
                bench_obs, bench_serve, bench_tt, bench_roofline,
@@ -36,6 +38,7 @@ MODULES = {
 
 
 def main() -> None:
+    enable_compile_cache()
     names = sys.argv[1:] or list(MODULES)
     print("name,us_per_call,derived")
     for n in names:

@@ -318,6 +318,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     import argparse
     import os
 
+    from ..compile_cache import enable as enable_compile_cache
     from .measurements import (baseline_measurements, load_report,
                                simulator_sweep)
     from .preset import CalibratedTech
@@ -343,6 +344,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                     help="output directory for the CalibratedTech JSON")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     ms: List[Measurement] = []
     for src in (args.source or ["simulator"]):
         if src == "simulator":

@@ -58,7 +58,9 @@ def _positions(order):
 
 def _footprint(A, dmask, tile):
     """Tile footprint (elements) per tensor.  A: (T,D,L) int, tile: (L,)."""
-    span = jnp.einsum("tdl,l->td", A.astype(F), tile.astype(F))
+    # an elementwise product and sum, not a dot: a TPU runs an f32 dot at
+    # default precision in bf16, which rounds tile sizes above 256
+    span = jnp.sum(A.astype(F) * tile.astype(F), axis=-1)     # (T,D)
     nnz = jnp.sum(A != 0, axis=-1).astype(F)                  # (T,D)
     fd = jnp.where(dmask, span - jnp.maximum(nnz - 1.0, 0.0), 1.0)
     fd = jnp.maximum(fd, 1.0)

@@ -182,8 +182,8 @@ def make_sa(spec: SystemSpec, space: DesignSpace,
 
 
 # ---------------------------------------------------------------------------
-# Gaussian process + probability of improvement (from scratch; the Matern
-# covariance has a Pallas kernel in repro.kernels.gp_cov used on TPU)
+# Gaussian process + probability of improvement (from scratch, in jnp; the
+# Pallas Matern kernel in repro.kernels.gp_cov is not on this path)
 # ---------------------------------------------------------------------------
 def matern52(X1, X2, lengthscale):
     d2 = jnp.sum((X1[:, None, :] - X2[None, :, :]) ** 2, -1)
@@ -352,9 +352,7 @@ def _optimize_impl(spec: SystemSpec, space: DesignSpace, key,
     from .constants import DEFAULT_TECH
     tech = tech or DEFAULT_TECH
     sa_run = make_sa(spec, space, sa_fields, sa, tech)
-    rng = np.random.default_rng(np.asarray(
-        jax.random.key_data(key) if hasattr(jax.random, "key_data")
-        else key)[-1])
+    rng = np.random.default_rng(np.asarray(jax.random.key_data(key))[-1])
 
     X, Y, designs = [], [], []
     history = []

@@ -412,6 +412,11 @@ class ParetoArchive:
                      else jnp.asarray(mask, bool))
         new_designs = {k: jnp.asarray(v).reshape((m,) + self.designs[k].shape[1:])
                        for k, v in designs.items()}
+        # the archive is one-device state: a batch sharded over an island
+        # mesh comes to the default device first, since the update's
+        # Mosaic dominance kernel cannot be partitioned automatically
+        objs, new_valid, new_designs = jax.device_put(
+            (objs, new_valid, new_designs), jax.devices()[0])
         o, v, d = _archive_update(
             jnp.asarray(self.objs), jnp.asarray(self.valid),
             {k: jnp.asarray(v) for k, v in self.designs.items()},

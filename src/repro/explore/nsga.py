@@ -38,7 +38,6 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec
 
 from ..core.encoding import (ALL_FIELDS, DesignSpace, feasibility_penalty,
@@ -197,7 +196,7 @@ def make_nsga(spec: SystemSpec, space: DesignSpace,
         body = _build_run(space, dims, idx, cfg, tech, n_isl=n_isl)
         if mesh is not None:
             P = PartitionSpec
-            body = shard_map(
+            body = jax.shard_map(
                 body, mesh=mesh,
                 # (key, pop0, arr, imm): key + spec arrays replicated,
                 # population sharded on its leading axis, immigrants on
@@ -211,7 +210,7 @@ def make_nsga(spec: SystemSpec, space: DesignSpace,
                 out_specs=(P(ISLAND_AXIS), P(ISLAND_AXIS), P(ISLAND_AXIS),
                            P(None, ISLAND_AXIS), P(None, ISLAND_AXIS),
                            P(None, ISLAND_AXIS), P()),
-                check_rep=False)
+                check_vma=False)
         _NSGA_CACHE[cache_key] = (
             jax.jit(body), imm_fn, n_imm, dict(executed=False))
     jitted, imm_fn, n_imm, state = _NSGA_CACHE[cache_key]

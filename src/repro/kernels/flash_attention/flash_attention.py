@@ -23,13 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # VMEM scratch: TPU memory space (falls back for interpret mode)
-    import jax.experimental.pallas.tpu as pltpu
-    def _vmem(shape):
-        return pltpu.VMEM(shape, jnp.float32)
-except Exception:  # pragma: no cover
-    def _vmem(shape):
-        return jax.ShapeDtypeStruct(shape, jnp.float32)
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
@@ -130,7 +124,9 @@ def flash_attention_pallas(q, k, v, mask_kind: str = "causal",
         out_specs=pl.BlockSpec((1, 1, bq, Dv),
                                lambda bh, qi, ki: (bh // H, bh % H, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, Dv), q.dtype),
-        scratch_shapes=[_vmem((bq, 1)), _vmem((bq, 1)), _vmem((bq, Dv))],
+        scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
+                        pltpu.VMEM((bq, 1), jnp.float32),
+                        pltpu.VMEM((bq, Dv), jnp.float32)],
         interpret=interpret,
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3)

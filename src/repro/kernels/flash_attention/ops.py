@@ -6,8 +6,8 @@
   KV blocks, O(S * block) live memory, identical FLOP count — so the
   compiled dry-run's cost/memory analysis reflects the kernelized program,
   not a naive O(S^2)-materialized one.
-* ``REPRO_PALLAS_INTERPRET=1`` forces the Pallas kernel in interpret mode
-  (kernel-correctness tests).
+* on CPU only, ``REPRO_PALLAS_INTERPRET=1`` forces the Pallas kernel in
+  interpret mode (kernel-correctness tests).
 """
 
 from __future__ import annotations
@@ -31,7 +31,8 @@ def _use_pallas() -> bool:
 
 
 def _force_interpret() -> bool:
-    return os.environ.get("REPRO_PALLAS_INTERPRET", "0") == "1"
+    return (jax.default_backend() == "cpu"
+            and os.environ.get("REPRO_PALLAS_INTERPRET", "0") == "1")
 
 
 def flash_attention_blocked(q, k, v, mask_kind: str = "causal",

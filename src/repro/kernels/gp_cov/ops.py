@@ -1,6 +1,7 @@
-"""Dispatching wrapper for the GP covariance: Pallas on TPU (padding to the
-tile grid), jnp reference elsewhere; REPRO_PALLAS_INTERPRET=1 forces the
-kernel in interpret mode."""
+"""Dispatching wrapper for the GP covariance: the compiled Pallas kernel on
+TPU (padding to the tile grid), the jnp reference elsewhere.  On CPU only,
+REPRO_PALLAS_INTERPRET=1 runs the kernel in interpret mode; a TPU never
+interprets and never falls back to the reference."""
 
 from __future__ import annotations
 
@@ -14,9 +15,10 @@ from .ref import matern52_ref
 
 
 def matern52(X1, X2, lengthscale: float = 0.3):
-    use_pallas = (jax.default_backend() == "tpu"
-                  or os.environ.get("REPRO_PALLAS_INTERPRET", "0") == "1")
-    if not use_pallas:
+    backend = jax.default_backend()
+    interpret = (backend == "cpu"
+                 and os.environ.get("REPRO_PALLAS_INTERPRET", "0") == "1")
+    if backend != "tpu" and not interpret:
         return matern52_ref(X1, X2, lengthscale)
     n, m = X1.shape[0], X2.shape[0]
     bn = 128 if n >= 128 else n
@@ -25,7 +27,5 @@ def matern52(X1, X2, lengthscale: float = 0.3):
     pm = (-m) % bm
     X1p = jnp.pad(X1, ((0, pn), (0, 0)))
     X2p = jnp.pad(X2, ((0, pm), (0, 0)))
-    K = matern52_pallas(
-        X1p, X2p, lengthscale,
-        interpret=os.environ.get("REPRO_PALLAS_INTERPRET", "0") == "1")
+    K = matern52_pallas(X1p, X2p, lengthscale, interpret=interpret)
     return K[:n, :m]

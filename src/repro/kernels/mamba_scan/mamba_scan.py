@@ -21,13 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    import jax.experimental.pallas.tpu as pltpu
-    def _vmem(shape):
-        return pltpu.VMEM(shape, jnp.float32)
-except Exception:  # pragma: no cover
-    def _vmem(shape):
-        return jax.ShapeDtypeStruct(shape, jnp.float32)
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_CHUNK = 64
 
@@ -92,7 +86,7 @@ def selective_scan_pallas(u, delta, A, Bc, Cc, h0=None,
             jax.ShapeDtypeStruct((B, S, Di), jnp.float32),
             jax.ShapeDtypeStruct((B, Di, Ds), jnp.float32),
         ],
-        scratch_shapes=[_vmem((Di, Ds))],
+        scratch_shapes=[pltpu.VMEM((Di, Ds), jnp.float32)],
         interpret=interpret,
     )(u.astype(jnp.float32), delta.astype(jnp.float32),
       A.astype(jnp.float32), Bc.astype(jnp.float32), Cc.astype(jnp.float32),

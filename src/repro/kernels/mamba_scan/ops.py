@@ -6,7 +6,8 @@
   (a1 a2, b1 a2 + b2)), so ``jax.lax.associative_scan`` gives an O(log S)
   depth program — the right lowering for CPU/dry-run and the second
   correctness reference against ``ref.py``.
-* ``REPRO_PALLAS_INTERPRET=1`` forces the Pallas kernel in interpret mode.
+* on CPU only, ``REPRO_PALLAS_INTERPRET=1`` forces the Pallas kernel in
+  interpret mode.
 """
 
 from __future__ import annotations
@@ -43,7 +44,8 @@ def selective_scan_assoc(u, delta, A, Bc, Cc, h0=None):
 
 
 def selective_scan(u, delta, A, Bc, Cc, h0=None):
-    if os.environ.get("REPRO_PALLAS_INTERPRET", "0") == "1":
+    if (jax.default_backend() == "cpu"
+            and os.environ.get("REPRO_PALLAS_INTERPRET", "0") == "1"):
         return selective_scan_pallas(u, delta, A, Bc, Cc, h0, interpret=True)
     if _use_pallas():
         return selective_scan_pallas(u, delta, A, Bc, Cc, h0)

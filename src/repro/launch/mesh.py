@@ -10,7 +10,7 @@ from __future__ import annotations
 import numpy as np
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -23,11 +23,8 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
         raise RuntimeError(
             f"mesh {shape} needs {n} devices, have {len(devs)} — run under "
             "launch/dryrun.py (it forces 512 host devices)")
-    try:
-        return jax.make_mesh(shape, axes)
-    except (ValueError, TypeError):
-        arr = np.asarray(devs[:n]).reshape(shape)
-        return Mesh(arr, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model_parallel: int = 1) -> Mesh:

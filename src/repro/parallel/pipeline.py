@@ -25,7 +25,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def pipeline_forward(stage_fn: Callable, mesh: Mesh, axis: str,
@@ -39,7 +38,7 @@ def pipeline_forward(stage_fn: Callable, mesh: Mesh, axis: str,
     pspec = jax.tree.map(lambda _: P(axis), params)
     others = tuple(a for a in mesh.axis_names if a != axis)
 
-    @partial(shard_map, mesh=mesh, check_rep=False,
+    @partial(jax.shard_map, mesh=mesh, check_vma=False,
              in_specs=(pspec, P()), out_specs=P())
     def run(p_local, xs):
         p_local = jax.tree.map(lambda a: a[0], p_local)   # strip stage dim

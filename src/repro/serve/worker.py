@@ -31,6 +31,7 @@ import json
 import sys
 import time
 
+from ..compile_cache import enable as enable_compile_cache
 from ..explore.api import Session
 from .executor import run_job
 from .jobs import JobStore
@@ -106,6 +107,7 @@ def main(argv=None) -> int:
                          "(test hook: widens the crash window)")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     store = JobStore(args.store)
     session = _session(args)
     for rec in store.recover():

@@ -256,7 +256,8 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.parallel.pipeline import pipeline_forward, split_stages
 
-mesh = jax.make_mesh((4,), ("stage",))
+mesh = jax.make_mesh((4,), ("stage",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 L, D, MB, M = 8, 16, 4, 8
 key = jax.random.PRNGKey(0)
 params = {"w": jax.random.normal(key, (L, D, D)) * (1.0 / np.sqrt(D))}
