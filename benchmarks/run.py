@@ -13,9 +13,8 @@ from repro.compile_cache import enable as enable_compile_cache
 
 from . import (bench_validation, bench_cost_fig3, bench_comparison,
                bench_codesign, bench_pareto, bench_explore, bench_transfer,
-               bench_obs, bench_serve, bench_tt, bench_roofline,
-               bench_autoshard, bench_kernels, bench_scale,
-               bench_surrogate)
+               bench_serve, bench_tt, bench_roofline, bench_autoshard,
+               bench_kernels, bench_scale, bench_surrogate)
 from .common import QUICK, emit
 
 MODULES = {
@@ -26,7 +25,6 @@ MODULES = {
     "pareto": bench_pareto,            # Fig. 9
     "explore": bench_explore,          # repro.explore front + cache service
     "transfer": bench_transfer,        # cross-workload transfer warm-starts
-    "obs": bench_obs,                  # flight-recorder overhead + journal
     "serve": bench_serve,              # async jobs, overload, crash-resume
     "tt": bench_tt,                    # Fig. 10 case study
     "roofline": bench_roofline,        # dry-run roofline table

@@ -117,169 +117,183 @@ def evaluate_arrays(arrays: Dict, design: Dict, dims: Tuple[int, int, int],
 
     # ---- per-workload chiplet analysis (per pipeline tick) -----------------
     def analyze_one(wi, ext_bw):
-        wl = {k: arr[k][wi] for k in
-              ("bounds", "loopmask", "A", "tmask", "dmask", "is_out")}
-        wl = dict(wl)
-        wl["bounds"] = _tick_bounds(wl["bounds"], wl["loopmask"],
-                                    design["pipe"][wi],
-                                    (2 ** design["logB"]).astype(jnp.int32))
-        return analyze_chiplet(wl, design["shape"][wi], design["spatial"][wi],
-                               design["order"][wi], design["tiling"][wi],
-                               tech=tech, ext_bw_gbps=ext_bw)
+        with jax.named_scope("dataflow"):
+            wl = {k: arr[k][wi] for k in
+                  ("bounds", "loopmask", "A", "tmask", "dmask", "is_out")}
+            wl = dict(wl)
+            wl["bounds"] = _tick_bounds(wl["bounds"], wl["loopmask"],
+                                        design["pipe"][wi],
+                                        (2 ** design["logB"]).astype(
+                                            jnp.int32))
+            return analyze_chiplet(wl, design["shape"][wi],
+                                   design["spatial"][wi], design["order"][wi],
+                                   design["tiling"][wi], tech=tech,
+                                   ext_bw_gbps=ext_bw)
 
     an0 = jax.vmap(lambda wi: analyze_one(wi, cap))(jnp.arange(W))
     d_stage0 = an0["delay_ns"]                                  # (W,)
 
-    n_chips = an0["n_chiplets"].astype(jnp.int32)               # (W,)
-    base = jnp.cumsum(n_chips) - n_chips                        # global chiplet base
-    n_nodes = jnp.sum(n_chips)
-    placement = design["placement"]                             # (W*CH,)
+    with jax.named_scope("network"):
+        n_chips = an0["n_chiplets"].astype(jnp.int32)               # (W,)
+        base = jnp.cumsum(n_chips) - n_chips        # global chiplet base
+        n_nodes = jnp.sum(n_chips)
+        placement = design["placement"]                             # (W*CH,)
 
-    # ---- communication graph (flows) ---------------------------------------
-    # block A: DRAM->chiplet external-input streams  (W*CH flows)
-    # block B: chiplet->DRAM final-output writebacks (W*CH flows)
-    # block C: producer->consumer intermediate flows (E*CH flows)
-    ch_ids = jnp.arange(CH)
+        # ---- communication graph (flows) ------------------------------------
+        # block A: DRAM->chiplet external-input streams  (W*CH flows)
+        # block B: chiplet->DRAM final-output writebacks (W*CH flows)
+        # block C: producer->consumer intermediate flows (E*CH flows)
+        ch_ids = jnp.arange(CH)
 
-    def wl_chip_node(wi, j):
-        g = jnp.clip(base[wi] + j, 0, W * CH - 1)
-        return placement[g]
+        def wl_chip_node(wi, j):
+            g = jnp.clip(base[wi] + j, 0, W * CH - 1)
+            return placement[g]
 
-    wgrid = jnp.repeat(jnp.arange(W), CH)                       # (W*CH,)
-    jgrid = jnp.tile(ch_ids, W)
-    chip_valid = jgrid < n_chips[wgrid]
-    node_of = jax.vmap(wl_chip_node)(wgrid, jgrid)              # (W*CH,)
+        wgrid = jnp.repeat(jnp.arange(W), CH)                       # (W*CH,)
+        jgrid = jnp.tile(ch_ids, W)
+        chip_valid = jgrid < n_chips[wgrid]
+        node_of = jax.vmap(wl_chip_node)(wgrid, jgrid)              # (W*CH,)
 
-    ein = an0["ext_in_bytes_t"]                                 # (W, T) per chiplet
-    eout = an0["ext_out_bytes_t"]
-    dram_in_vol = jnp.sum(ein * arr["ext_in"], axis=1)[wgrid]   # (W*CH,)
-    dram_out_vol = jnp.sum(eout * arr["fin_out"], axis=1)[wgrid]
+        ein = an0["ext_in_bytes_t"]             # (W, T) per chiplet
+        eout = an0["ext_out_bytes_t"]
+        dram_in_vol = jnp.sum(ein * arr["ext_in"], axis=1)[wgrid]   # (W*CH,)
+        dram_out_vol = jnp.sum(eout * arr["fin_out"], axis=1)[wgrid]
 
-    dram_node = n_nodes
-    srcA = jnp.full((W * CH,), 0, jnp.int32) + dram_node
-    dstA = node_of
-    volA, mA = dram_in_vol, chip_valid & (dram_in_vol > 0)
-    srcB, dstB = node_of, jnp.full((W * CH,), 0, jnp.int32) + dram_node
-    volB, mB = dram_out_vol, chip_valid & (dram_out_vol > 0)
+        dram_node = n_nodes
+        srcA = jnp.full((W * CH,), 0, jnp.int32) + dram_node
+        dstA = node_of
+        volA, mA = dram_in_vol, chip_valid & (dram_in_vol > 0)
+        srcB, dstB = node_of, jnp.full((W * CH,), 0, jnp.int32) + dram_node
+        volB, mB = dram_out_vol, chip_valid & (dram_out_vol > 0)
 
-    egrid = jnp.repeat(jnp.arange(E), CH)                       # (E*CH,)
-    jg = jnp.tile(ch_ids, E)
-    w1, w2 = arr["esrc"][egrid], arr["edst"][egrid]
-    mC = arr["emask"][egrid] & (jg < n_chips[w2])
-    volC = ein[w2, arr["edst_tensor"][egrid]]                   # per consumer chiplet
-    srcC = jax.vmap(wl_chip_node)(w1, jg % jnp.maximum(n_chips[w1], 1))
-    dstC = jax.vmap(wl_chip_node)(w2, jg)
+        egrid = jnp.repeat(jnp.arange(E), CH)                       # (E*CH,)
+        jg = jnp.tile(ch_ids, E)
+        w1, w2 = arr["esrc"][egrid], arr["edst"][egrid]
+        mC = arr["emask"][egrid] & (jg < n_chips[w2])
+        volC = ein[w2, arr["edst_tensor"][egrid]]   # per consumer chiplet
+        srcC = jax.vmap(wl_chip_node)(w1, jg % jnp.maximum(n_chips[w1], 1))
+        dstC = jax.vmap(wl_chip_node)(w2, jg)
 
-    src = jnp.concatenate([srcA, srcB, srcC]).astype(jnp.int32)
-    dst = jnp.concatenate([dstA, dstB, dstC]).astype(jnp.int32)
-    vol = jnp.concatenate([volA, volB, volC])
-    fmask = jnp.concatenate([mA, mB, mC])
-    fw_src = jnp.concatenate([wgrid, wgrid, w1])                # stage of src
-    fw_dst = jnp.concatenate([wgrid, wgrid, w2])
-    is_dram_f = jnp.concatenate([jnp.ones_like(mA), jnp.ones_like(mB),
-                                 jnp.zeros_like(mC)])
+        src = jnp.concatenate([srcA, srcB, srcC]).astype(jnp.int32)
+        dst = jnp.concatenate([dstA, dstB, dstC]).astype(jnp.int32)
+        vol = jnp.concatenate([volA, volB, volC])
+        fmask = jnp.concatenate([mA, mB, mC])
+        fw_src = jnp.concatenate([wgrid, wgrid, w1])    # stage of src
+        fw_dst = jnp.concatenate([wgrid, wgrid, w2])
+        is_dram_f = jnp.concatenate([jnp.ones_like(mA), jnp.ones_like(mB),
+                                     jnp.zeros_like(mC)])
 
-    # bwr_{i,j} = |Omega| / min(D(v_i), D(v_j))  (DRAM side: consumer delay)
-    d_src = jnp.where(is_dram_f > 0, BIG, d_stage0[fw_src])
-    d_min = jnp.minimum(d_src, d_stage0[fw_dst])
-    bwr = vol / jnp.maximum(d_min, 1.0)
+        # bwr_{i,j} = |Omega| / min(D(v_i), D(v_j))
+        # (DRAM side: consumer delay)
+        d_src = jnp.where(is_dram_f > 0, BIG, d_stage0[fw_src])
+        d_min = jnp.minimum(d_src, d_stage0[fw_dst])
+        bwr = vol / jnp.maximum(d_min, 1.0)
 
-    # ---- network: provision at hotspot, cap by packaging -------------------
-    nh_all = jnp.asarray(next_hop_tables())
-    tcode = design["family"] * (MAX_NODES + 1) + jnp.clip(n_nodes, 1, MAX_NODES)
-    nh = nh_all[tcode]
-    pre = evaluate_network(nh, src, dst, bwr, vol, fmask,
-                           cap, tech.dram_bw, tech.router_delay_ns, n_nodes)
-    link_bw = jnp.minimum(jnp.maximum(pre["hotspot"], 1.0), cap)
-    net = evaluate_network(nh, src, dst, bwr, vol, fmask,
-                           link_bw, tech.dram_bw, tech.router_delay_ns,
-                           n_nodes)
+        # ---- network: provision at hotspot, cap by packaging ----------------
+        nh_all = jnp.asarray(next_hop_tables())
+        tcode = (design["family"] * (MAX_NODES + 1)
+                 + jnp.clip(n_nodes, 1, MAX_NODES))
+        nh = nh_all[tcode]
+        pre = evaluate_network(nh, src, dst, bwr, vol, fmask,
+                               cap, tech.dram_bw, tech.router_delay_ns,
+                               n_nodes)
+        link_bw = jnp.minimum(jnp.maximum(pre["hotspot"], 1.0), cap)
+        net = evaluate_network(nh, src, dst, bwr, vol, fmask,
+                               link_bw, tech.dram_bw, tech.router_delay_ns,
+                               n_nodes)
 
-    # ---- fixed-point pass: refine stage delays with achieved inbound bw ----
-    # DRAM streaming overlaps compute INSIDE the stage (max(D_C, D_B, D_A),
-    # Sec III-C); each workload's effective external bandwidth per chiplet is
-    # what its block-A flows achieved under contention.
-    ebw_f = jnp.where(fmask, vol / jnp.maximum(net["delay_ns"], 1.0), 0.0)
-    ebw_A = ebw_f[: W * CH]
-    inbound = jnp.zeros((W,), F).at[wgrid].add(jnp.where(mA, ebw_A, 0.0))
-    per_chip_bw = inbound / jnp.maximum(an0["n_chiplets"], 1.0)
-    per_chip_bw = jnp.where(per_chip_bw > 0, per_chip_bw, cap)
+        # ---- fixed-point pass: refine stage delays with achieved inbound bw
+        # DRAM streaming overlaps compute INSIDE the stage
+        # (max(D_C, D_B, D_A), Sec III-C); each workload's effective
+        # external bandwidth per chiplet is what its block-A flows
+        # achieved under contention.
+        ebw_f = jnp.where(fmask, vol / jnp.maximum(net["delay_ns"], 1.0), 0.0)
+        ebw_A = ebw_f[: W * CH]
+        inbound = jnp.zeros((W,), F).at[wgrid].add(jnp.where(mA, ebw_A, 0.0))
+        per_chip_bw = inbound / jnp.maximum(an0["n_chiplets"], 1.0)
+        per_chip_bw = jnp.where(per_chip_bw > 0, per_chip_bw, cap)
     an = jax.vmap(lambda wi, bw: analyze_one(wi, bw))(
         jnp.arange(W), jnp.minimum(per_chip_bw, cap))
     d_stage = an["delay_ns"]                                    # (W,)
 
-    # ---- transfer-stage delays ---------------------------------------------
-    # DRAM in/out contributes only the FIRST/LAST tile fill to the path (the
-    # bulk is overlapped inside the compute stage); producer->consumer edges
-    # are full pipeline transfer stages D(e) = max over the edge's flows.
-    fdel = jnp.where(fmask, net["delay_ns"], 0.0)
-    hop_lat = net["hops"] * F(tech.router_delay_ns)
-    tiles_w = jnp.maximum(an["ext_tiles"], 1.0)                 # (W,)
-    first_fill = hop_lat + (fdel - hop_lat) / tiles_w[
-        jnp.concatenate([wgrid, wgrid, w1])]
-    d_in = jnp.zeros((W,), F).at[wgrid].max(
-        jnp.where(mA, first_fill[: W * CH], 0.0))
-    d_out = jnp.zeros((W,), F).at[wgrid].max(
-        jnp.where(mB, first_fill[W * CH: 2 * W * CH], 0.0))
-    eflow = fdel[2 * W * CH:]
-    d_edge = jnp.zeros((E,), F).at[egrid].max(jnp.where(mC, eflow, 0.0))
+    with jax.named_scope("network"):
+        # ---- transfer-stage delays ------------------------------------------
+        # DRAM in/out contributes only the FIRST/LAST tile fill to the
+        # path (the bulk is overlapped inside the compute stage);
+        # producer->consumer edges are full pipeline transfer stages
+        # D(e) = max over the edge's flows.
+        fdel = jnp.where(fmask, net["delay_ns"], 0.0)
+        hop_lat = net["hops"] * F(tech.router_delay_ns)
+        tiles_w = jnp.maximum(an["ext_tiles"], 1.0)                 # (W,)
+        first_fill = hop_lat + (fdel - hop_lat) / tiles_w[
+            jnp.concatenate([wgrid, wgrid, w1])]
+        d_in = jnp.zeros((W,), F).at[wgrid].max(
+            jnp.where(mA, first_fill[: W * CH], 0.0))
+        d_out = jnp.zeros((W,), F).at[wgrid].max(
+            jnp.where(mB, first_fill[W * CH: 2 * W * CH], 0.0))
+        eflow = fdel[2 * W * CH:]
+        d_edge = jnp.zeros((E,), F).at[egrid].max(jnp.where(mC, eflow, 0.0))
 
-    # ---- DAG longest path (max-plus relaxation over edges) -----------------
-    dist = d_in + d_stage                                       # (W,)
-    def relax(dist, _):
-        upd = dist[arr["esrc"]] + d_edge + d_stage[arr["edst"]]
-        upd = jnp.where(arr["emask"], upd, -BIG)
-        return dist.at[arr["edst"]].max(upd), None
-    dist, _ = jax.lax.scan(relax, dist, None, length=W)
-    lat_tick = jnp.max(dist + d_out)
+        # ---- DAG longest path (max-plus relaxation over edges) --------------
+        dist = d_in + d_stage                                       # (W,)
+        def relax(dist, _):
+            upd = dist[arr["esrc"]] + d_edge + d_stage[arr["edst"]]
+            upd = jnp.where(arr["emask"], upd, -BIG)
+            return dist.at[arr["edst"]].max(upd), None
+        dist, _ = jax.lax.scan(relax, dist, None, length=W)
+        lat_tick = jnp.max(dist + d_out)
 
-    max_stage = jnp.maximum(
-        jnp.max(d_stage),
-        jnp.maximum(jnp.max(jnp.where(arr["emask"], d_edge, 0.0)),
-                    jnp.maximum(jnp.max(d_in), jnp.max(d_out))))
-    latency = lat_tick + (B - 1.0) * max_stage
-    throughput = 1.0 / jnp.maximum(max_stage, 1e-9)
+        max_stage = jnp.maximum(
+            jnp.max(d_stage),
+            jnp.maximum(jnp.max(jnp.where(arr["emask"], d_edge, 0.0)),
+                        jnp.maximum(jnp.max(d_in), jnp.max(d_out))))
+        latency = lat_tick + (B - 1.0) * max_stage
+        throughput = 1.0 / jnp.maximum(max_stage, 1e-9)
 
-    # ---- energy -------------------------------------------------------------
-    e_compute = jnp.sum(jax.vmap(
-        lambda i: chiplet_energy_pj({k: v[i] for k, v in an.items()}, tech))(
-            jnp.arange(W))) * B
-    e_net = system_network_energy_pj(net, pkg, tech) * B
-    energy = e_compute + e_net
+    with jax.named_scope("energy_cost"):
+        # ---- energy ---------------------------------------------------------
+        e_compute = jnp.sum(jax.vmap(
+            lambda i: chiplet_energy_pj({k: v[i] for k, v in an.items()},
+                                        tech))(jnp.arange(W))) * B
+        e_net = system_network_energy_pj(net, pkg, tech) * B
+        energy = e_compute + e_net
 
-    # ---- area / cost --------------------------------------------------------
-    area_w = jax.vmap(
-        lambda i: chiplet_area_mm2({k: v[i] for k, v in an.items()},
-                                   link_bw, pkg, tech))(jnp.arange(W))  # (W,)
-    die_areas = jnp.where(chip_valid, area_w[wgrid], 0.0)       # (W*CH,)
-    cost = package_cost(die_areas, pkg, tech)
-    area = jnp.sum(die_areas)
+        # ---- area / cost ----------------------------------------------------
+        area_w = jax.vmap(
+            lambda i: chiplet_area_mm2({k: v[i] for k, v in an.items()},
+                                       link_bw, pkg, tech))(
+                jnp.arange(W))                                  # (W,)
+        die_areas = jnp.where(chip_valid, area_w[wgrid], 0.0)       # (W*CH,)
+        cost = package_cost(die_areas, pkg, tech)
+        area = jnp.sum(die_areas)
 
-    # ---- calibration corrections -------------------------------------------
-    # Per-metric multiplicative factors fitted by repro.calib; all default to
-    # 1.0 (exact multiplicative identity), so the uncalibrated model returns
-    # bit-identical numbers to a build without this block.
-    cl, ce = F(tech.corr_latency), F(tech.corr_energy)
-    ca, cc = F(tech.corr_area), F(tech.corr_cost)
-    latency, lat_tick = latency * cl, lat_tick * cl
-    throughput = throughput / cl
-    d_stage, d_edge = d_stage * cl, d_edge * cl
-    e_compute, e_net = e_compute * ce, e_net * ce
-    energy = energy * ce
-    cost, area = cost * cc, area * ca
+        # ---- calibration corrections ----------------------------------------
+        # Per-metric multiplicative factors fitted by repro.calib; all
+        # default to 1.0 (exact multiplicative identity), so the
+        # uncalibrated model returns bit-identical numbers to a build
+        # without this block.
+        cl, ce = F(tech.corr_latency), F(tech.corr_energy)
+        ca, cc = F(tech.corr_area), F(tech.corr_cost)
+        latency, lat_tick = latency * cl, lat_tick * cl
+        throughput = throughput / cl
+        d_stage, d_edge = d_stage * cl, d_edge * cl
+        e_compute, e_net = e_compute * ce, e_net * ce
+        energy = energy * ce
+        cost, area = cost * cc, area * ca
 
-    return dict(
-        latency_ns=latency, lat_tick_ns=lat_tick, throughput_per_ns=throughput,
-        energy_pj=energy, edp=energy * 1e-12 * latency * 1e-9,
-        cost_usd=cost, area_mm2=area,
-        utilization=jnp.sum(an["utilization"] * an["n_chiplets"])
-        / jnp.maximum(jnp.sum(an["n_chiplets"]), 1.0),
-        hotspot_gbps=pre["hotspot"], link_bw_gbps=link_bw,
-        n_nodes=n_nodes, stage_delays_ns=d_stage, edge_delays_ns=d_edge,
-        energy_compute_pj=e_compute, energy_network_pj=e_net,
-        dram_bytes=net["dram_bytes"] * B,
-        d2d_byte_hops=net["d2d_byte_hops"] * B,
-    )
+        return dict(
+            latency_ns=latency, lat_tick_ns=lat_tick,
+            throughput_per_ns=throughput,
+            energy_pj=energy, edp=energy * 1e-12 * latency * 1e-9,
+            cost_usd=cost, area_mm2=area,
+            utilization=jnp.sum(an["utilization"] * an["n_chiplets"])
+            / jnp.maximum(jnp.sum(an["n_chiplets"]), 1.0),
+            hotspot_gbps=pre["hotspot"], link_bw_gbps=link_bw,
+            n_nodes=n_nodes, stage_delays_ns=d_stage, edge_delays_ns=d_edge,
+            energy_compute_pj=e_compute, energy_network_pj=e_net,
+            dram_bytes=net["dram_bytes"] * B,
+            d2d_byte_hops=net["d2d_byte_hops"] * B,
+        )
 
 
 def make_batch_evaluator(spec: SystemSpec, tech: TechConstants = DEFAULT_TECH):

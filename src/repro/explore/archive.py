@@ -340,14 +340,17 @@ def _archive_update(objs, valid, designs, new_objs, new_valid, new_designs):
     a_designs = jax.tree.map(
         lambda x, y: jnp.concatenate([x, y], axis=0), designs, new_designs)
 
-    nd = dominance_counts(a_objs, a_valid)
-    front = (nd == 0) & a_valid
-    crowd = crowding_distance(a_objs, front)
-    # ranking (ascending): nondominated by descending crowding (boundary
-    # points carry inf crowding => kept first), then dominated/invalid rows.
-    keyv = jnp.where(front, -jnp.minimum(crowd, F(1e9)),
-                     F(BIG) + nd.astype(F))
-    order = jnp.argsort(keyv)[:cap]
+    with jax.named_scope("dominance"):
+        nd = dominance_counts(a_objs, a_valid)
+        front = (nd == 0) & a_valid
+    with jax.named_scope("crowding"):
+        crowd = crowding_distance(a_objs, front)
+        # ranking (ascending): nondominated by descending crowding
+        # (boundary points carry inf crowding => kept first), then
+        # dominated/invalid rows.
+        keyv = jnp.where(front, -jnp.minimum(crowd, F(1e9)),
+                         F(BIG) + nd.astype(F))
+        order = jnp.argsort(keyv)[:cap]
     return (a_objs[order], front[order],
             jax.tree.map(lambda x: x[order], a_designs))
 
@@ -405,25 +408,33 @@ class ParetoArchive:
 
     def insert(self, designs: Dict, objs, mask=None, count_evals=True):
         """Insert a stacked batch: ``designs`` leaves (m, ...), ``objs``
-        (m, n_obj).  Non-finite objective rows are dropped."""
-        objs = jnp.asarray(objs, F).reshape(-1, self.n_obj)
-        m = objs.shape[0]
-        new_valid = (jnp.ones(m, bool) if mask is None
-                     else jnp.asarray(mask, bool))
-        new_designs = {k: jnp.asarray(v).reshape((m,) + self.designs[k].shape[1:])
-                       for k, v in designs.items()}
-        # the archive is one-device state: a batch sharded over an island
-        # mesh comes to the default device first, since the update's
-        # Mosaic dominance kernel cannot be partitioned automatically
-        objs, new_valid, new_designs = jax.device_put(
-            (objs, new_valid, new_designs), jax.devices()[0])
-        o, v, d = _archive_update(
-            jnp.asarray(self.objs), jnp.asarray(self.valid),
-            {k: jnp.asarray(v) for k, v in self.designs.items()},
-            objs, new_valid, new_designs)
-        self.objs = np.asarray(o)
-        self.valid = np.asarray(v)
-        self.designs = {k: np.asarray(x) for k, x in d.items()}
+        (m, n_obj), ``mask`` (m,); leading batch axes (e.g. a scan's
+        (generations, pop)) are flattened into m.  Non-finite objective
+        rows are dropped.  Dispatching the update and reading its result
+        back are two spans, ``archive.insert`` and ``explore.fetch``:
+        the read is where the host waits for the device."""
+        with obs.span("archive.insert"):
+            objs = jnp.asarray(objs, F).reshape(-1, self.n_obj)
+            m = objs.shape[0]
+            new_valid = (jnp.ones(m, bool) if mask is None
+                         else jnp.asarray(mask, bool).reshape(m))
+            new_designs = {
+                k: jnp.asarray(v).reshape((m,) + self.designs[k].shape[1:])
+                for k, v in designs.items()}
+            # the archive is one-device state: a batch sharded over an
+            # island mesh comes to the default device first, since the
+            # update's Mosaic dominance kernel cannot be partitioned
+            # automatically
+            objs, new_valid, new_designs = jax.device_put(
+                (objs, new_valid, new_designs), jax.devices()[0])
+            o, v, d = _archive_update(
+                jnp.asarray(self.objs), jnp.asarray(self.valid),
+                {k: jnp.asarray(v) for k, v in self.designs.items()},
+                objs, new_valid, new_designs)
+        with obs.span("explore.fetch"):
+            self.objs = np.asarray(o)
+            self.valid = np.asarray(v)
+            self.designs = {k: np.asarray(x) for k, x in d.items()}
         if count_evals:
             self.n_evals += int(m)
         return self

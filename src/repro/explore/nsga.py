@@ -395,10 +395,11 @@ def _build_run_gated(space, dims, idx, cfg, tech, n_exact: int,
 
     def eval_one(d, arr):
         m = evaluate_arrays(arr, d, dims, tech)
-        raw = metric_stack(m)
-        p = feasibility_penalty(space, d, m)
-        sel = log_metric_stack(m)[obj_idx] + 8.0 * jnp.log(p)
-        return raw, sel, p <= 1.0 + 1e-6
+        with jax.named_scope("selection"):
+            raw = metric_stack(m)
+            p = feasibility_penalty(space, d, m)
+            sel = log_metric_stack(m)[obj_idx] + 8.0 * jnp.log(p)
+            return raw, sel, p <= 1.0 + 1e-6
 
     def eval_pop(pop, arr):
         return jax.vmap(lambda d: eval_one(d, arr))(pop)
@@ -468,49 +469,53 @@ def _build_run_gated(space, dims, idx, cfg, tech, n_exact: int,
 
     def step(arr, sur, carry, k, imm_g):
         pop, raw, sel, feas, hv_run, best_run = carry
-        k_mate, k_cx, k_mut = jax.random.split(k, 3)
-        nl = jnp.sum(arr["loopmask"], axis=1).astype(jnp.int32)
+        with jax.named_scope("variation"):
+            k_mate, k_cx, k_mut = jax.random.split(k, 3)
+            nl = jnp.sum(arr["loopmask"], axis=1).astype(jnp.int32)
 
-        # --- variation: IDENTICAL to the ungated scan (same PRNG uses)
-        partners = jax.random.randint(k_mate, (N,), 0, N)
-        mates = jax.tree.map(lambda x: x[partners], pop)
-        children = jax.vmap(crossover)(jax.random.split(k_cx, N), pop, mates)
-        for r in range(cfg.mutations):
-            kr = jax.random.split(jax.random.fold_in(k_mut, r), N)
-            children = jax.vmap(
-                lambda kk, d: mutate(kk, d, space, cfg.fields,
-                                     nl=nl, bounds=arr["bounds"]))(
-                kr, children)
-        if n_imm:
-            children = jax.tree.map(
-                lambda c, f: c.at[:n_imm].set(f), children, imm_g)
+            # --- variation: IDENTICAL to the ungated scan (same PRNG uses)
+            partners = jax.random.randint(k_mate, (N,), 0, N)
+            mates = jax.tree.map(lambda x: x[partners], pop)
+            children = jax.vmap(crossover)(jax.random.split(k_cx, N), pop,
+                                           mates)
+            for r in range(cfg.mutations):
+                kr = jax.random.split(jax.random.fold_in(k_mut, r), N)
+                children = jax.vmap(
+                    lambda kk, d: mutate(kk, d, space, cfg.fields,
+                                         nl=nl, bounds=arr["bounds"]))(
+                    kr, children)
+            if n_imm:
+                children = jax.tree.map(
+                    lambda c, f: c.at[:n_imm].set(f), children, imm_g)
 
         # --- surrogate pre-filter: exact-evaluate only the chosen slots
-        order, n_forced, dis_mean = gate(sur, children)
-        picked = jax.tree.map(lambda x: x[order], children)
+        with jax.named_scope("selection"):
+            order, n_forced, dis_mean = gate(sur, children)
+            picked = jax.tree.map(lambda x: x[order], children)
         craw, csel, cfeas = eval_pop(picked, arr)
 
         # --- environmental selection over the N + n_exact pool
-        a_pop = jax.tree.map(lambda x, y: jnp.concatenate([x, y]),
-                             pop, picked)
-        a_raw = jnp.concatenate([raw, craw])
-        a_sel = jnp.concatenate([sel, csel])
-        a_feas = jnp.concatenate([feas, cfeas])
-        finite = jnp.all(jnp.isfinite(a_sel), axis=-1)
-        a_sane = jnp.where(jnp.isfinite(a_sel), a_sel, F(BIG))
-        nd = dominance_counts(a_sane, finite)
-        crowd = crowding_distance(a_sane, finite)
-        keyv = jnp.where(finite,
-                         nd.astype(F) * F(1e6) - jnp.minimum(crowd, F(1e5)),
-                         F(BIG))
-        order_s = jnp.argsort(keyv)[:N]
-        pop_n = jax.tree.map(lambda x: x[order_s], a_pop)
-        raw_n = a_raw[order_s]
-        sel_n, feas_n = a_sel[order_s], a_feas[order_s]
-        hv_run, best_run, tr = telemetry(sel_n, feas_n, cfeas,
-                                         hv_run, best_run)
-        tr["forced_exact"] = n_forced
-        tr["disagreement"] = dis_mean
+        with jax.named_scope("selection"):
+            a_pop = jax.tree.map(lambda x, y: jnp.concatenate([x, y]),
+                                 pop, picked)
+            a_raw = jnp.concatenate([raw, craw])
+            a_sel = jnp.concatenate([sel, csel])
+            a_feas = jnp.concatenate([feas, cfeas])
+            finite = jnp.all(jnp.isfinite(a_sel), axis=-1)
+            a_sane = jnp.where(jnp.isfinite(a_sel), a_sel, F(BIG))
+            nd = dominance_counts(a_sane, finite)
+            crowd = crowding_distance(a_sane, finite)
+            keyv = jnp.where(
+                finite, nd.astype(F) * F(1e6) - jnp.minimum(crowd, F(1e5)),
+                F(BIG))
+            order_s = jnp.argsort(keyv)[:N]
+            pop_n = jax.tree.map(lambda x: x[order_s], a_pop)
+            raw_n = a_raw[order_s]
+            sel_n, feas_n = a_sel[order_s], a_feas[order_s]
+            hv_run, best_run, tr = telemetry(sel_n, feas_n, cfeas,
+                                             hv_run, best_run)
+            tr["forced_exact"] = n_forced
+            tr["disagreement"] = dis_mean
         return ((pop_n, raw_n, sel_n, feas_n, hv_run, best_run),
                 (picked, craw, cfeas, tr))
 
@@ -544,10 +549,12 @@ def _build_run(space, dims, idx, cfg, tech, n_isl: int = 1):
 
     def eval_one(d, arr):
         m = evaluate_arrays(arr, d, dims, tech)
-        raw = metric_stack(m)
-        p = feasibility_penalty(space, d, m)
-        sel = log_metric_stack(m)[obj_idx] + 8.0 * jnp.log(p)
-        return raw, sel, p <= 1.0 + 1e-6       # feasible <=> no penalty
+        with jax.named_scope("selection"):
+            # the penalized log-objectives selection ranks on
+            raw = metric_stack(m)
+            p = feasibility_penalty(space, d, m)
+            sel = log_metric_stack(m)[obj_idx] + 8.0 * jnp.log(p)
+            return raw, sel, p <= 1.0 + 1e-6   # feasible <=> no penalty
 
     def eval_pop(pop, arr):
         return jax.vmap(lambda d: eval_one(d, arr))(pop)
@@ -600,26 +607,35 @@ def _build_run(space, dims, idx, cfg, tech, n_isl: int = 1):
 
     def step(arr, carry, k, imm_g, g):
         pop, raw, sel, feas, hv_run, best_run = carry
-        k_mate, k_cx, k_mut = jax.random.split(k, 3)
-        nl = jnp.sum(arr["loopmask"], axis=1).astype(jnp.int32)
+        with jax.named_scope("variation"):
+            k_mate, k_cx, k_mut = jax.random.split(k, 3)
+            nl = jnp.sum(arr["loopmask"], axis=1).astype(jnp.int32)
 
-        # --- variation: whole-field crossover with a random mate, then a
-        # few chained single-field mutate moves (the SA neighborhood)
-        partners = jax.random.randint(k_mate, (N,), 0, N)
-        mates = jax.tree.map(lambda x: x[partners], pop)
-        children = jax.vmap(crossover)(jax.random.split(k_cx, N), pop, mates)
-        for r in range(cfg.mutations):
-            kr = jax.random.split(jax.random.fold_in(k_mut, r), N)
-            children = jax.vmap(
-                lambda kk, d: mutate(kk, d, space, cfg.fields,
-                                     nl=nl, bounds=arr["bounds"]))(
-                kr, children)
-        if n_imm:
-            # random immigrants fight convergence collapse of the front
-            children = jax.tree.map(
-                lambda c, f: c.at[:n_imm].set(f), children, imm_g)
+            # --- variation: whole-field crossover with a random mate,
+            # then a few chained single-field mutate moves (the SA
+            # neighborhood)
+            partners = jax.random.randint(k_mate, (N,), 0, N)
+            mates = jax.tree.map(lambda x: x[partners], pop)
+            children = jax.vmap(crossover)(jax.random.split(k_cx, N), pop,
+                                           mates)
+            for r in range(cfg.mutations):
+                kr = jax.random.split(jax.random.fold_in(k_mut, r), N)
+                children = jax.vmap(
+                    lambda kk, d: mutate(kk, d, space, cfg.fields,
+                                         nl=nl, bounds=arr["bounds"]))(
+                    kr, children)
+            if n_imm:
+                # random immigrants fight convergence collapse of the
+                # front
+                children = jax.tree.map(
+                    lambda c, f: c.at[:n_imm].set(f), children, imm_g)
         craw, csel, cfeas = eval_pop(children, arr)
+        with jax.named_scope("selection"):
+            return select(pop, raw, sel, feas, hv_run, best_run,
+                          children, craw, csel, cfeas, g)
 
+    def select(pop, raw, sel, feas, hv_run, best_run, children, craw, csel,
+               cfeas, g):
         # --- environmental selection over the 2N parent+child pool
         a_pop = jax.tree.map(lambda x, y: jnp.concatenate([x, y]),
                              pop, children)

@@ -734,25 +734,27 @@ class ExplorationService:
         may open a group the sequential loop later revisits."""
         if "warm" in g:
             return g["warm"]
-        arc = g["arc"] = self.archive_for(g["spec"], g["space"], key=ck)
-        g["embedding"] = workload_features(g["spec"].graph)
-        budget = g["budget"] = max(q.budget for q in g["queries"])
-        union = g["union"] = tuple(
-            k for k in METRIC_KEYS
-            if any(k in q.objectives for q in g["queries"]))
-        warm = self.warm_verdict(arc, union, budget)
-        obs.inc("explore.cache.hit" if warm else "explore.cache.miss")
-        g.update(warm=warm, n_run=0, trace=None, plateaued=False,
-                 banked=0, realloc=0, transferred_from=(), n_seeds=0,
-                 interrupted=False, plateau=PlateauState(),
-                 # any group member asking for surrogate gating turns it
-                 # on for the shared run (like budget: max wins)
-                 surrogate=next((q.surrogate for q in g["queries"]
-                                 if q.surrogate is not None), None),
-                 sur_used=False, sur_hits=0, sur_fallbacks=0)
-        if warm and ck not in self.manifest.entries:
-            self._update_manifest(ck, g)         # backfill pre-manifest
-            #                                      caches into the index
+        with obs.span("explore.open_group", key=ck):
+            arc = g["arc"] = self.archive_for(g["spec"], g["space"],
+                                              key=ck)
+            g["embedding"] = workload_features(g["spec"].graph)
+            budget = g["budget"] = max(q.budget for q in g["queries"])
+            union = g["union"] = tuple(
+                k for k in METRIC_KEYS
+                if any(k in q.objectives for q in g["queries"]))
+            warm = self.warm_verdict(arc, union, budget)
+            obs.inc("explore.cache.hit" if warm else "explore.cache.miss")
+            g.update(warm=warm, n_run=0, trace=None, plateaued=False,
+                     banked=0, realloc=0, transferred_from=(), n_seeds=0,
+                     interrupted=False, plateau=PlateauState(),
+                     # any group member asking for surrogate gating turns
+                     # it on for the shared run (like budget: max wins)
+                     surrogate=next((q.surrogate for q in g["queries"]
+                                     if q.surrogate is not None), None),
+                     sur_used=False, sur_hits=0, sur_fallbacks=0)
+            if warm and ck not in self.manifest.entries:
+                self._update_manifest(ck, g)     # backfill pre-manifest
+                #                                  caches into the index
         return warm
 
     def _group_seeds(self, ck: str, g: Dict, key) -> Optional[Dict]:
@@ -780,30 +782,31 @@ class ExplorationService:
                          interrupted: bool) -> None:
         """Shared epilogue of one group's refinement: archive accounting,
         eval/bank counters, trust calibration and manifest/disk sync."""
-        arc, union, budget = g["arc"], g["union"], g["budget"]
-        arc.searched = tuple(k for k in METRIC_KEYS
-                             if k in arc.searched or k in union)
-        if not interrupted:
-            # an interrupted run must NOT mark the budget covered —
-            # the resumed attempt still owes the residual segments
-            arc.budget_covered = max(arc.budget_covered, budget)
-        obs.inc("explore.evals.spent", n_run)
-        if banked:
-            obs.inc("explore.evals.banked", banked)
-            self.ledger[ck] = self.ledger.get(ck, 0) + banked
-        g.update(n_run=n_run, trace=trace, plateaued=plateaued,
-                 banked=banked, interrupted=interrupted)
-        if sp is not None:
-            sp.set(n_run=n_run, plateaued=plateaued, banked=banked,
-                   n_seeds=g["n_seeds"], interrupted=interrupted)
-        if trace is not None:           # a stop before the first segment
-            arc.trace_summary = trace.summary()         # leaves no trace
-        self.save(ck)
-        m = self.manifest               # ONE snapshot: the trust records
-        #                                 land in the same object the
-        #                                 index update saves below
-        self._record_trust(ck, g, trace, m)
-        self._update_manifest(ck, g, m)
+        with obs.span("explore.book", key=ck):
+            arc, union, budget = g["arc"], g["union"], g["budget"]
+            arc.searched = tuple(k for k in METRIC_KEYS
+                                 if k in arc.searched or k in union)
+            if not interrupted:
+                # an interrupted run must NOT mark the budget covered —
+                # the resumed attempt still owes the residual segments
+                arc.budget_covered = max(arc.budget_covered, budget)
+            obs.inc("explore.evals.spent", n_run)
+            if banked:
+                obs.inc("explore.evals.banked", banked)
+                self.ledger[ck] = self.ledger.get(ck, 0) + banked
+            g.update(n_run=n_run, trace=trace, plateaued=plateaued,
+                     banked=banked, interrupted=interrupted)
+            if sp is not None:
+                sp.set(n_run=n_run, plateaued=plateaued, banked=banked,
+                       n_seeds=g["n_seeds"], interrupted=interrupted)
+            if trace is not None:       # a stop before the first segment
+                arc.trace_summary = trace.summary()     # leaves no trace
+            self.save(ck)
+            m = self.manifest           # ONE snapshot: the trust records
+            #                             land in the same object the
+            #                             index update saves below
+            self._record_trust(ck, g, trace, m)
+            self._update_manifest(ck, g, m)
 
     def _refine_group(self, ck: str, g: Dict, key, on_segment=None,
                       seq=None, resume: bool = False,
@@ -970,15 +973,16 @@ class ExplorationService:
         hv_pairs = [(METRIC_KEYS.index(union[i]),
                      METRIC_KEYS.index(union[j]))
                     for i, j in objective_pairs(len(union))]
-        for ln in lanes:
-            k_init, k_run = jax.random.split(ln["key"])
-            space = ln["g"]["space"]
-            ln.update(
-                k_run=k_run, trace=None, plateaued=False,
-                interrupted=False, spent_g=0, live=True,
-                st=ln["g"]["plateau"],
-                filler=jax.vmap(lambda k: random_design(k, space))(
-                    jax.random.split(k_init, pop)))
+        with obs.span("explore.init_population", lanes=len(lanes)):
+            for ln in lanes:
+                k_init, k_run = jax.random.split(ln["key"])
+                space = ln["g"]["space"]
+                ln.update(
+                    k_run=k_run, trace=None, plateaued=False,
+                    interrupted=False, spent_g=0, live=True,
+                    st=ln["g"]["plateau"],
+                    filler=jax.vmap(lambda k: random_design(k, space))(
+                        jax.random.split(k_init, pop)))
         for s in range(n_seg):
             live = [ln for ln in lanes if ln["live"]]
             if not live:
@@ -990,29 +994,34 @@ class ExplorationService:
             t_seg = time.perf_counter()
             compiled = not run.compile_state["executed"]
             slots = live + [live[0]] * (lanes_pad - len(live))
-            keys_s = [jax.random.fold_in(ln["k_run"], s) for ln in slots]
-            pops = [_seed_population(ln["g"]["arc"], pop, ln["filler"],
-                                     ln["seeds"] if s == 0 else None)
-                    for ln in slots]
-            pop_stack = jax.tree.map(lambda *xs: jnp.stack(xs), *pops)
-            pop_s, _raw, _sel, ev_d, ev_r, ev_f, tr = run(
-                keys_s, pop_stack,
-                [ln["g"]["spec"].arrays for ln in slots])
+            with obs.span("explore.seed", lanes=len(slots)):
+                pops = [_seed_population(ln["g"]["arc"], pop, ln["filler"],
+                                         ln["seeds"] if s == 0 else None)
+                        for ln in slots]
+                pop_stack = jax.tree.map(lambda *xs: jnp.stack(xs), *pops)
+            with obs.span("explore.dispatch", lanes=len(slots)):
+                keys_s = [jax.random.fold_in(ln["k_run"], s)
+                          for ln in slots]
+                pop_s, _raw, _sel, ev_d, ev_r, ev_f, tr = run(
+                    keys_s, pop_stack,
+                    [ln["g"]["spec"].arrays for ln in slots])
             # per-lane booking: identical to one sequential _refine
             # segment; padding slots (j >= len(live)) book nothing
             staged = []
             for j, ln in enumerate(live):
                 arc = ln["g"]["arc"]
-                arc.insert(
-                    jax.tree.map(
-                        lambda x: x[j].reshape((-1,) + x.shape[3:]), ev_d),
-                    ev_r[j].reshape(-1, ev_r.shape[-1]),
-                    mask=ev_f[j].reshape(-1), count_evals=False)
+                with obs.span("archive.insert"):
+                    lane = (jax.tree.map(lambda x: x[j], ev_d), ev_r[j],
+                            ev_f[j])
+                arc.insert(lane[0], lane[1], mask=lane[2],
+                           count_evals=False)
                 arc.n_evals += pop * chunk
                 ln["spent_g"] += chunk
-                ln["filler"] = jax.tree.map(lambda x: x[j], pop_s)
-                seg_trace = ConvergenceTrace.from_scan(
-                    union, {k: v[j] for k, v in tr.items()}, pop)
+                with obs.span("explore.seed"):
+                    ln["filler"] = jax.tree.map(lambda x: x[j], pop_s)
+                tr_j = {k: v[j] for k, v in tr.items()}
+                with obs.span("explore.fetch"):
+                    seg_trace = ConvergenceTrace.from_scan(union, tr_j, pop)
                 hv_now = np.asarray([arc.projected_hypervolume(p)
                                      for p in hv_pairs])
                 seg_trace.archive_hv = hv_now[None, :]
@@ -1337,29 +1346,31 @@ class ExplorationService:
         """Phase 3: project every query's front out of the group archive.
         ``elapsed`` covers the group's own refinement (plus any
         reallocation top-up it received), not the whole batch."""
-        designs, metrics = g["arc"].front()
-        elapsed = g["elapsed"]
-        results = []
-        for q in g["queries"]:
-            idx = [METRIC_KEYS.index(o) for o in q.objectives]
-            cols = metrics[:, idx]
-            keep = pareto_front(cols) if len(cols) else []
-            results.append(ExploreResult(
-                objectives=q.objectives,
-                front_objs=cols[keep],
-                front_metrics=metrics[keep],
-                front_designs=[{k: v[i] for k, v in designs.items()}
-                               for i in keep],
-                from_cache=g["warm"], n_evals_run=g["n_run"],
-                elapsed_s=elapsed, cache_key=ck,
-                trace=g["trace"], plateaued=g["plateaued"],
-                n_evals_banked=g["banked"], n_evals_realloc=g["realloc"],
-                transferred_from=g["transferred_from"],
-                n_transfer_seeds=g["n_seeds"],
-                interrupted=g["interrupted"],
-                surrogate_used=g["sur_used"],
-                surrogate_hits=g["sur_hits"],
-                surrogate_fallbacks=g["sur_fallbacks"]))
+        with obs.span("explore.project", key=ck):
+            designs, metrics = g["arc"].front()
+            elapsed = g["elapsed"]
+            results = []
+            for q in g["queries"]:
+                idx = [METRIC_KEYS.index(o) for o in q.objectives]
+                cols = metrics[:, idx]
+                keep = pareto_front(cols) if len(cols) else []
+                results.append(ExploreResult(
+                    objectives=q.objectives,
+                    front_objs=cols[keep],
+                    front_metrics=metrics[keep],
+                    front_designs=[{k: v[i] for k, v in designs.items()}
+                                   for i in keep],
+                    from_cache=g["warm"], n_evals_run=g["n_run"],
+                    elapsed_s=elapsed, cache_key=ck,
+                    trace=g["trace"], plateaued=g["plateaued"],
+                    n_evals_banked=g["banked"],
+                    n_evals_realloc=g["realloc"],
+                    transferred_from=g["transferred_from"],
+                    n_transfer_seeds=g["n_seeds"],
+                    interrupted=g["interrupted"],
+                    surrogate_used=g["sur_used"],
+                    surrogate_hits=g["sur_hits"],
+                    surrogate_fallbacks=g["sur_fallbacks"]))
         return results
 
     def _effective_pop(self, budget: int, quantize_down: bool = False
@@ -1454,8 +1465,9 @@ class ExplorationService:
                 arrays["t_hv_gen"] = np.asarray(trace.hv_gen)
             arrays.update({f"d_{k}": np.asarray(v)
                            for k, v in arc.designs.items()})
-            arrays.update({f"f_{k}": np.asarray(v)
-                           for k, v in filler.items()})
+            with obs.span("explore.fetch"):
+                arrays.update({f"f_{k}": np.asarray(v)
+                               for k, v in filler.items()})
             with obs.span("explore.checkpoint", segment=int(s_next) - 1):
                 atomic_savez(path, __meta=np.frombuffer(
                     json.dumps(meta).encode(), dtype=np.uint8), **arrays)
@@ -1612,8 +1624,9 @@ class ExplorationService:
         def seed(filler, extra=None):
             return _seed_population(arc, pop, filler, extra)
 
-        filler = jax.vmap(lambda k: random_design(k, space))(
-            jax.random.split(k_init, pop))
+        with obs.span("explore.init_population"):
+            filler = jax.vmap(lambda k: random_design(k, space))(
+                jax.random.split(k_init, pop))
         st = plateau if plateau is not None else PlateauState()
         trace = None
         plateaued, interrupted, spent_g = False, False, 0
@@ -1644,31 +1657,30 @@ class ExplorationService:
             # histogram aren't polluted by one-off compiles
             active = run_g if run_g is not None else run
             compiled = not active.compile_state["executed"]
-            if run_g is not None:
-                pop_s, _raw, _sel, ev_designs, ev_raw, ev_feas, tr = run_g(
-                    jax.random.fold_in(k_run, s),
-                    seed(filler, seeds if s == 0 else None), sur)
-                per_gen = n_exact       # only the gate's exact slots cost
-            else:
-                pop_s, _raw, _sel, ev_designs, ev_raw, ev_feas, tr = run(
-                    jax.random.fold_in(k_run, s),
-                    seed(filler, seeds if s == 0 else None))
-                per_gen = pop
+            with obs.span("explore.seed"):
+                pop0 = seed(filler, seeds if s == 0 else None)
+            with obs.span("explore.dispatch"):
+                if run_g is not None:
+                    pop_s, _raw, _sel, ev_designs, ev_raw, ev_feas, tr = \
+                        run_g(jax.random.fold_in(k_run, s), pop0, sur)
+                    per_gen = n_exact   # only the gate's exact slots cost
+                else:
+                    pop_s, _raw, _sel, ev_designs, ev_raw, ev_feas, tr = \
+                        run(jax.random.fold_in(k_run, s), pop0)
+                    per_gen = pop
             # archive EVERY evaluation of the segment, not just the
             # survivors — masked to feasible designs so the archive (and
             # every front served from it) never carries a
-            # constraint-violating point
-            arc.insert(
-                jax.tree.map(lambda x: x.reshape((-1,) + x.shape[2:]),
-                             ev_designs),
-                ev_raw.reshape(-1, ev_raw.shape[-1]),
-                mask=ev_feas.reshape(-1), count_evals=False)
+            # constraint-violating point; ``insert`` flattens the
+            # (generations, pop) axes
+            arc.insert(ev_designs, ev_raw, mask=ev_feas, count_evals=False)
             arc.n_evals += per_gen * chunk  # one vmapped evaluation per
             spent_g += chunk                # step (gated: exact slots)
             spent_e += per_gen * chunk
             filler = pop_s
-            seg_trace = ConvergenceTrace.from_scan(objectives, tr,
-                                                   per_gen)
+            with obs.span("explore.fetch"):
+                seg_trace = ConvergenceTrace.from_scan(objectives, tr,
+                                                       per_gen)
             if run_g is not None:
                 skipped = (pop - n_exact) * chunk
                 sur_stats["used"] = True

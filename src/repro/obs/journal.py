@@ -27,8 +27,8 @@ cache key):
   snapshots, and dropped ``on_segment`` deliveries.
 
 ``replay`` folds a record stream back into per-key run summaries — the
-completeness check ``benchmarks.bench_obs`` gates on (journal segment
-count and final hypervolume must match the in-memory ``Result``).
+completeness check ``tests/test_obs.py`` makes (journal segment count
+and final hypervolume must match the in-memory ``Result``).
 
 Enable journaling per session (``Session(journal=...)``) or fleet-wide
 via ``$REPRO_JOURNAL_DIR`` — ``default_journal()`` lazily creates one
@@ -205,7 +205,8 @@ def replay(records: Union[Sequence[Dict], Iterator[Dict]]) -> Dict[str, Dict]:
     ``final_hv`` is the first column of the last segment's
     archive-projected hypervolume row (the quantity the plateau detector
     monitors and ``ConvergenceTrace.archive_hv`` carries in memory) —
-    the invariant ``bench_obs`` replays against the in-memory result."""
+    the invariant ``tests/test_obs.py`` replays against the in-memory
+    result."""
     out: Dict[str, Dict] = {}
     last_t: Dict[str, Dict] = {}
 

@@ -11,8 +11,8 @@ early); reallocation top-ups appear under their own phase.  A fleet
 summary follows: query count, cache hit rate, evaluations/second, and
 exact p50/p90/p99 time-to-front over the journaled results.
 
-``render(records)`` returns the report as a string (what ``bench_obs``
-gates on); ``main`` prints it.
+``render(records)`` returns the report as a string (what
+``tests/test_obs.py`` checks); ``main`` prints it.
 """
 
 from __future__ import annotations

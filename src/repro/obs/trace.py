@@ -13,6 +13,13 @@ The contract the instrumented search stack relies on:
   ``inc``/``observe``/``emit`` return immediately.  Instrumentation
   never touches PRNG keys or numeric state, so results are bit-identical
   with observability on or off — disabling only removes the clock reads.
+* **On the profiler's clock**: every span also opens a
+  ``jax.profiler.TraceAnnotation`` of its name, so under a profiler
+  session (``jax.profiler.trace``) each span is an event on the host
+  plane of the trace, on the same clock as the device's operations.
+  The annotation class is looked up on the first span, so importing
+  ``repro.obs`` never imports JAX; without JAX the annotation is a
+  no-op.
 * ``emit(record)`` fans a dict record out to the attached sinks (the
   crash-safe JSONL journals of ``repro.obs.journal``); ``add_sink`` /
   ``remove_sink`` / the ``sink_attached`` context manager manage the
@@ -175,6 +182,21 @@ def gauge(name: str, v: float) -> None:
 # ---------------------------------------------------------------------------
 # spans
 # ---------------------------------------------------------------------------
+_ANNOTATION = None
+
+
+def _annotation(name: str):
+    """A profiler annotation named ``name`` (not yet entered); a no-op
+    context where JAX is not installed."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation as _ANNOTATION
+        except ImportError:
+            _ANNOTATION = lambda _name: contextlib.nullcontext()  # noqa
+    return _ANNOTATION(name)
+
+
 def _stack() -> List[str]:
     s = getattr(_TLS, "stack", None)
     if s is None:
@@ -183,19 +205,21 @@ def _stack() -> List[str]:
 
 
 class Span:
-    """One live span: monotonic start on ``__enter__``; on ``__exit__``
-    the duration lands in the ``span.<name>`` histogram and (when a
-    journal is attached) one ``span`` record with the span's attrs,
-    depth, and parent span name.  ``set(**attrs)`` adds attributes to a
-    live span (e.g. an outcome computed mid-block)."""
+    """One live span: monotonic start on ``__enter__``, inside a
+    profiler annotation of the span's name; on ``__exit__`` the duration
+    lands in the ``span.<name>`` histogram and (when a journal is
+    attached) one ``span`` record with the span's attrs, depth, and
+    parent span name.  ``set(**attrs)`` adds attributes to a live span
+    (e.g. an outcome computed mid-block)."""
 
-    __slots__ = ("name", "attrs", "t0", "elapsed_s")
+    __slots__ = ("name", "attrs", "t0", "elapsed_s", "_ann")
 
     def __init__(self, name: str, attrs: Dict):
         self.name = name
         self.attrs = attrs
         self.t0 = 0.0
         self.elapsed_s = 0.0
+        self._ann = None
 
     def set(self, **attrs) -> "Span":
         self.attrs.update(attrs)
@@ -203,11 +227,15 @@ class Span:
 
     def __enter__(self) -> "Span":
         _stack().append(self.name)
+        self._ann = _annotation(self.name)
+        self._ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         self.elapsed_s = time.perf_counter() - self.t0
+        self._ann.__exit__(exc_type, exc, tb)
+        self._ann = None
         stack = _stack()
         stack.pop()
         REGISTRY.histogram(f"span.{self.name}").observe(self.elapsed_s)

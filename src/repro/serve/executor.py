@@ -173,6 +173,7 @@ class JobHandle:
         self._stale: Optional[Result] = None
         self._error: Optional[BaseException] = None
         self._cancelled = False
+        self._t_submit: Optional[float] = None  # Executor.submit entry
 
     # ---- state ----------------------------------------------------------
     def record(self) -> Optional[JobRecord]:
@@ -312,6 +313,7 @@ class Executor:
         ``key`` is an integer PRNG seed (default 0): the job store must
         rebuild the exact key chain on a resume or in another process,
         so an opaque key array is not accepted."""
+        t_submit = time.perf_counter()
         if query.resolved_engine() != "nsga":
             raise ValueError(
                 "submit_async serves the nsga engine (resumable scan "
@@ -333,6 +335,7 @@ class Executor:
         ck = tsess._cache_key(query.problem)
         rec = self.store.create(payload, query.problem.key(), ck, seed)
         handle = JobHandle(rec.job_id, self.store)
+        handle._t_submit = t_submit
         self._handles[rec.job_id] = handle
         obs.inc("serve.submitted")
         if not self._admit(deadline_s):
@@ -384,6 +387,10 @@ class Executor:
 
     # ---- the worker body -------------------------------------------------
     def _run_job(self, handle: JobHandle) -> None:
+        if handle._t_submit is not None:    # queue wait of a submitted
+            #                                 job (recovered ones have none)
+            obs.observe("serve.queue_wait_s",
+                        time.perf_counter() - handle._t_submit)
         try:
             rec = self.store.claim(handle.job_id)
             if rec is None:         # cancelled, or another worker won

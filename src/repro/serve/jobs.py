@@ -29,6 +29,7 @@ import warnings
 from pathlib import Path
 from typing import Dict, List, Optional, Tuple
 
+from .. import obs
 from ..core.workload import Edge, TensorRef, Workload, WorkloadGraph
 from ..explore.locks import file_lock
 
@@ -134,7 +135,9 @@ class JobStore:
     ``os.replace``.  ``claim`` is the only compound operation: under the
     lock it re-reads the record, verifies it is still claimable, and
     flips it to RUNNING owned by this PID — two workers draining one
-    store can never both win a job."""
+    store can never both win a job.  ``create``, ``update`` and
+    ``claim`` each run inside one ``serve.store`` span, lock and atomic
+    replace included."""
 
     def __init__(self, root):
         self.root = Path(root)
@@ -148,11 +151,13 @@ class JobStore:
     # ---- CRUD -----------------------------------------------------------
     def create(self, payload: Dict, problem_key: str, cache_key: str,
                seed: int) -> JobRecord:
-        rec = JobRecord(
-            job_id=uuid.uuid4().hex[:12], state=PENDING, payload=payload,
-            problem_key=problem_key, cache_key=cache_key, seed=int(seed),
-            created_t=time.time(), updated_t=time.time())
-        self._write(rec)
+        with obs.span("serve.store", op="create"):
+            rec = JobRecord(
+                job_id=uuid.uuid4().hex[:12], state=PENDING,
+                payload=payload, problem_key=problem_key,
+                cache_key=cache_key, seed=int(seed),
+                created_t=time.time(), updated_t=time.time())
+            self._write(rec)
         return rec
 
     def get(self, job_id: str) -> Optional[JobRecord]:
@@ -176,9 +181,10 @@ class JobStore:
             tmp.unlink(missing_ok=True)
 
     def update(self, rec: JobRecord, **fields) -> JobRecord:
-        for k, v in fields.items():
-            setattr(rec, k, v)
-        self._write(rec)
+        with obs.span("serve.store", op="update"):
+            for k, v in fields.items():
+                setattr(rec, k, v)
+            self._write(rec)
         return rec
 
     def jobs(self) -> List[JobRecord]:
@@ -199,7 +205,7 @@ class JobStore:
         """Atomically take ownership of one PENDING job: under the store
         lock, re-read, verify claimable, flip to RUNNING owned by this
         PID.  ``None`` when someone else won (or the job advanced)."""
-        with file_lock(self._lock):
+        with obs.span("serve.store", op="claim"), file_lock(self._lock):
             rec = self.get(job_id)
             if rec is None or rec.state != PENDING:
                 return None

@@ -7,8 +7,10 @@ observability on or off, journal replay against the in-memory ``Result``,
 and the plan-vs-actual report."""
 
 import json
+import os
 import threading
 import warnings
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -444,3 +446,138 @@ def test_report_cli_renders_journal(tmp_path, capsys):
     assert main([str(jp)]) == 0
     out = capsys.readouterr().out
     assert "== plan vs actual ==" in out and "refine" in out
+
+
+# ---------------------------------------------------------------------------
+# spans on the profiler's clock, named scopes inside the compiled programs
+# ---------------------------------------------------------------------------
+def _profiled_submit(tmp_path):
+    """One tiny ``Session.submit`` under ``jax.profiler``: the host-thread
+    events of the trace, ``(name, start_ns, end_ns)`` in start order."""
+    import jax
+    from jax.profiler import ProfileData
+    q = Query(_problem(), budget=32)
+    _session(tmp_path / "warm").submit(q)         # compile outside
+    s = _session(tmp_path / "cold")
+    with jax.profiler.trace(str(tmp_path / "trace")):
+        s.submit(q)
+    path = next((tmp_path / "trace").rglob("*.xplane.pb"))
+    pd = ProfileData.from_file(str(path))
+    return sorted(((ev.name, int(ev.start_ns),
+                    int(ev.start_ns + ev.duration_ns))
+                   for plane in pd.planes if plane.name.startswith("/host")
+                   for line in plane.lines for ev in line.events),
+                  key=lambda e: e[1])
+
+
+def test_spans_are_profiler_trace_events(tmp_path):
+    evs = _profiled_submit(tmp_path)
+
+    def only(name):
+        found = [e for e in evs if e[0] == name]
+        assert len(found) == 1, (name, found)
+        return found[0]
+
+    def inside(inner, outer):
+        return outer[1] <= inner[1] and inner[2] <= outer[2]
+
+    submit = only("session.submit")
+    group = only("explore.refine_group")
+    dispatch = only("explore.dispatch")
+    fetches = [e for e in evs if e[0] == "explore.fetch"]
+    assert inside(group, submit)
+    assert inside(dispatch, group)
+    assert fetches and all(inside(f, group) for f in fetches)
+    assert dispatch[2] <= fetches[0][1]     # dispatched, then read back
+    # the sibling spans under the refinement never overlap each other
+    leaves = sorted((e for e in evs if e[0] in (
+        "explore.init_population", "explore.seed", "explore.dispatch",
+        "archive.insert", "explore.fetch")), key=lambda e: e[1])
+    assert all(a[2] <= b[1] for a, b in zip(leaves, leaves[1:]))
+
+
+def test_spans_work_without_jax():
+    """``repro.obs`` imports nothing of JAX; its spans still time and
+    count where JAX cannot be imported."""
+    import subprocess
+    import sys
+    code = ("import sys; sys.modules['jax'] = None\n"
+            "from repro import obs\n"
+            "assert 'jax' not in [m for m in sys.modules if sys.modules[m]]\n"
+            "with obs.span('x'):\n    pass\n"
+            "assert obs.REGISTRY.histogram('span.x').count == 1\n")
+    src = str(Path(obs.__file__).resolve().parents[2])
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, env={**os.environ, "PYTHONPATH": src})
+    assert r.returncode == 0, r.stderr
+
+
+def test_disabled_spans_leave_no_trace_events(tmp_path):
+    obs.disable()
+    try:
+        evs = _profiled_submit(tmp_path)
+    finally:
+        obs.enable()
+    assert not [e for e in evs
+                if e[0].startswith(("explore.", "session.", "archive."))]
+
+
+def _scopes(text):
+    """Every name-stack component of a lowered program's locations, with
+    transform wrappers (``vmap(network)``) taken off."""
+    import re
+    out = set()
+    for loc in re.findall(r'loc\("([^"]*/[^"]*)"', text):
+        for part in loc.split("/"):
+            while (m := re.fullmatch(r"\w+\((.*)\)", part)):
+                part = m.group(1)
+            out.add(part)
+    return out
+
+
+def test_compiled_programs_carry_named_scopes():
+    """The scan and the archive update name the search's layers in their
+    op metadata, each scope one path component."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core.encoding import random_design
+    from repro.explore.archive import _archive_update
+    from repro.explore.nsga import _NSGA_CACHE, make_nsga
+    p = _problem()
+    cfg = NSGAConfig(pop=8, generations=2)
+    make_nsga(p.spec, p.space, OBJ, cfg)
+    jitted, imm_fn, n_imm, _ = next(v for k, v in _NSGA_CACHE.items()
+                                    if cfg in k and k[-1] is None)
+    pop0 = jax.vmap(lambda k: random_design(k, p.space))(
+        jax.random.split(jax.random.PRNGKey(0), cfg.pop))
+    arr = {k: jnp.asarray(v) for k, v in p.spec.arrays.items()}
+    kk = jax.random.split(jax.random.PRNGKey(1), cfg.generations * n_imm)
+    imm = imm_fn(kk.reshape(cfg.generations, n_imm, *kk.shape[1:]),
+                 jnp.sum(arr["loopmask"], axis=1).astype(jnp.int32),
+                 arr["bounds"])
+    scan = _scopes(jitted.lower(jax.random.PRNGKey(2), pop0, arr,
+                                imm).as_text(debug_info=True))
+    assert {"dataflow", "network", "energy_cost", "variation",
+            "selection"} <= scan
+    objs = jnp.zeros((16, 4), jnp.float32)
+    valid = jnp.zeros(16, bool)
+    designs = jax.tree.map(lambda x: x[:16], pop0) if cfg.pop >= 16 else \
+        jax.tree.map(lambda x: jnp.concatenate([x, x]), pop0)
+    upd = _scopes(_archive_update.lower(objs, valid, designs, objs, valid,
+                                        designs).as_text(debug_info=True))
+    assert {"dominance", "crowding"} <= upd
+
+
+def test_job_store_spans_and_queue_wait(tmp_path):
+    from repro.serve import Executor
+    sess = _session(tmp_path)
+    store0 = obs.REGISTRY.histogram("span.serve.store").count
+    wait0 = obs.REGISTRY.histogram("serve.queue_wait_s").count
+    ex = Executor(sess, store=tmp_path / "jobs", max_workers=1)
+    try:
+        ex.submit(Query(_problem(), budget=16), key=3).result(timeout=300)
+    finally:
+        ex.shutdown(wait=True)
+    # create, claim, and the DONE update
+    assert obs.REGISTRY.histogram("span.serve.store").count - store0 == 3
+    assert obs.REGISTRY.histogram("serve.queue_wait_s").count - wait0 == 1
