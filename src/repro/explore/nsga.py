@@ -38,8 +38,10 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec
 
+from .. import obs
 from ..core.encoding import (ALL_FIELDS, DesignSpace, feasibility_penalty,
                              mutate, random_design)
 from ..core.evaluate import SystemSpec, evaluate_arrays
@@ -104,6 +106,63 @@ def pmx(key, a, b):
 # compiled runners keyed like the SA cache: padded dims + static config
 _NSGA_CACHE: dict = {}
 
+# compiled design samplers and design leaf shapes, keyed by _sampler_key
+_SAMPLERS: dict = {}
+_TEMPLATES: dict = {}
+
+
+def _sampler_key(space: DesignSpace):
+    """The statics ``random_design`` reads when ``nl``/``bounds`` come in
+    as runtime arrays — and nothing else: no ``space.spec`` content, so
+    problems that differ only in their workload share one program."""
+    return (space.W, space.CH, space.max_shape, space.max_logB,
+            space.fixed_packaging, space.fixed_family, space.allow_pipeline)
+
+
+def sample_designs(key, space: DesignSpace, n, nl=None, bounds=None) -> Dict:
+    """``n`` uniform random designs of ``space`` drawn by ONE compiled
+    program: bit for bit ``jax.vmap(lambda k: random_design(k, space))(
+    jax.random.split(key, prod(n)))``, each leaf reshaped to lead with
+    ``n`` (an int, or a tuple of batch dims).  Run eagerly that vmap is
+    dozens of single-op dispatches (one per permutation row); here it is
+    one, cached by ``_sampler_key`` and ``n``.
+
+    ``nl``/``bounds`` (default: ``space``'s own) are runtime operands —
+    the contract of ``mutate`` and of ``_static_key`` — so a cache hit for
+    a statics-equal but different problem draws from that problem's
+    bounds.  Counts ``explore.sampler.calls`` on every call and
+    ``explore.sampler.compiles`` on every program built."""
+    shape = (n,) if isinstance(n, int) else tuple(n)
+    ck = _sampler_key(space) + (shape,)
+    obs.inc("explore.sampler.calls")
+    fn = _SAMPLERS.get(ck)
+    if fn is None:
+        obs.inc("explore.sampler.compiles")
+
+        def draw(k, nl, b):
+            ks = jax.random.split(k, int(np.prod(shape)))
+            d = jax.vmap(lambda kk: random_design(kk, space, nl=nl,
+                                                  bounds=b))(ks)
+            return {f: v.reshape(shape + v.shape[1:]) for f, v in d.items()}
+
+        fn = _SAMPLERS[ck] = jax.jit(draw)
+    return fn(key, space.n_loops if nl is None else nl,
+              space.bounds if bounds is None else bounds)
+
+
+def design_template(space: DesignSpace) -> Dict:
+    """One all-zero design with the leaf shapes and dtypes
+    ``random_design`` gives in ``space`` — what a ``ParetoArchive``
+    template is read for.  Traced once per ``_sampler_key`` (no device
+    work, no retrace per problem); a fresh zero copy per call."""
+    ck = _sampler_key(space)
+    avals = _TEMPLATES.get(ck)
+    if avals is None:
+        avals = _TEMPLATES[ck] = jax.eval_shape(
+            lambda k: random_design(k, space),
+            jax.eval_shape(jax.random.PRNGKey, 0))
+    return {f: np.zeros(a.shape, a.dtype) for f, a in avals.items()}
+
 
 def _static_key(dims, idx, cfg, tech, space):
     """Everything compile-relevant about one scan variant EXCEPT how it is
@@ -119,6 +178,18 @@ def _static_key(dims, idx, cfg, tech, space):
     return (dims, idx, cfg, tech, space.max_shape, space.max_logB,
             space.max_total_pes, space.fixed_packaging,
             space.fixed_family, space.allow_pipeline)
+
+
+def _immigrants(k_imm, space, cfg, n_imm, loopmask, bounds):
+    """One run's fresh random designs, stacked (generations, n_imm, ...),
+    or ``None`` without immigrants.  ``n_loops``/``bounds`` come from the
+    run's workload arrays, not from ``space``: the cached sampler carries
+    NO workload content, so a cache hit for a statics-equal but different
+    problem stays content-correct."""
+    if not n_imm:
+        return None
+    nl = jnp.sum(loopmask, axis=1).astype(jnp.int32)
+    return sample_designs(k_imm, space, (cfg.generations, n_imm), nl, bounds)
 
 
 def make_nsga(spec: SystemSpec, space: DesignSpace,
@@ -182,17 +253,11 @@ def make_nsga(spec: SystemSpec, space: DesignSpace,
 
     cache_key = _static_key(dims, idx, cfg, tech, space) + (mesh,)
     if cache_key not in _NSGA_CACHE:
-        n_imm = int(round((cfg.pop // n_isl) * cfg.immigrants)) * n_isl
         # immigrants are drawn OUTSIDE the scanned/jitted evolution (as a
-        # scan input) — random_design's permutation sorts are expensive to
-        # compile and belong in one small vmapped kernel, not in the body.
-        # nl/bounds come in as runtime arrays (not baked from `space`) so
-        # the cached sampler carries NO workload content: a cache hit for
-        # a statics-equal but different problem stays content-correct
-        imm_fn = jax.jit(jax.vmap(jax.vmap(
-            lambda k, nl, b: random_design(k, space, nl=nl, bounds=b),
-            in_axes=(0, None, None)),
-            in_axes=(0, None, None))) if n_imm else None
+        # scan input, by ``sample_designs``) — random_design's permutation
+        # sorts are expensive to compile and belong in one small vmapped
+        # kernel, not in the body
+        n_imm = int(round((cfg.pop // n_isl) * cfg.immigrants)) * n_isl
         body = _build_run(space, dims, idx, cfg, tech, n_isl=n_isl)
         if mesh is not None:
             P = PartitionSpec
@@ -211,19 +276,15 @@ def make_nsga(spec: SystemSpec, space: DesignSpace,
                            P(None, ISLAND_AXIS), P(None, ISLAND_AXIS),
                            P(None, ISLAND_AXIS), P()),
                 check_vma=False)
-        _NSGA_CACHE[cache_key] = (
-            jax.jit(body), imm_fn, n_imm, dict(executed=False))
-    jitted, imm_fn, n_imm, state = _NSGA_CACHE[cache_key]
+        _NSGA_CACHE[cache_key] = (jax.jit(body), n_imm,
+                                  dict(executed=False))
+    jitted, n_imm, state = _NSGA_CACHE[cache_key]
 
     def runner(key, pop0, arrays=None):
         arr = {k: jnp.asarray(v) for k, v in (arrays or spec.arrays).items()}
         k_run, k_imm = jax.random.split(jnp.asarray(key))
-        imm = None
-        if n_imm:
-            kk = jax.random.split(k_imm, cfg.generations * n_imm)
-            nl = jnp.sum(arr["loopmask"], axis=1).astype(jnp.int32)
-            imm = imm_fn(kk.reshape(cfg.generations, n_imm, *kk.shape[1:]),
-                         nl, arr["bounds"])
+        imm = _immigrants(k_imm, space, cfg, n_imm, arr["loopmask"],
+                          arr["bounds"])
         out = jitted(k_run, pop0, arr, imm)
         state["executed"] = True
         return out
@@ -270,15 +331,10 @@ def make_nsga_fused(spec: SystemSpec, space: DesignSpace,
 
     cache_key = _static_key(dims, idx, cfg, tech, space) + ("lanes", lanes)
     if cache_key not in _NSGA_CACHE:
-        n_imm = int(round(cfg.pop * cfg.immigrants))
-        imm_fn = jax.jit(jax.vmap(jax.vmap(
-            lambda k, nl, b: random_design(k, space, nl=nl, bounds=b),
-            in_axes=(0, None, None)),
-            in_axes=(0, None, None))) if n_imm else None
         _NSGA_CACHE[cache_key] = (
             jax.jit(jax.vmap(_build_run(space, dims, idx, cfg, tech))),
-            imm_fn, n_imm, dict(executed=False))
-    jitted, imm_fn, n_imm, state = _NSGA_CACHE[cache_key]
+            int(round(cfg.pop * cfg.immigrants)), dict(executed=False))
+    jitted, n_imm, state = _NSGA_CACHE[cache_key]
 
     def runner(keys, pops, arrays_seq):
         if len(keys) != lanes or len(arrays_seq) != lanes:
@@ -293,11 +349,8 @@ def make_nsga_fused(spec: SystemSpec, space: DesignSpace,
             k_run, k_imm = jax.random.split(jnp.asarray(key))
             k_runs.append(k_run)
             if n_imm:
-                kk = jax.random.split(k_imm, cfg.generations * n_imm)
-                nl = jnp.sum(arr["loopmask"][i], axis=1).astype(jnp.int32)
-                imms.append(imm_fn(
-                    kk.reshape(cfg.generations, n_imm, *kk.shape[1:]),
-                    nl, arr["bounds"][i]))
+                imms.append(_immigrants(k_imm, space, cfg, n_imm,
+                                        arr["loopmask"][i], arr["bounds"][i]))
         imm = jax.tree.map(lambda *xs: jnp.stack(xs), *imms) \
             if n_imm else None
         out = jitted(jnp.stack(k_runs), pops, arr, imm)
@@ -348,28 +401,20 @@ def make_nsga_gated(spec: SystemSpec, space: DesignSpace,
     cache_key = _static_key(dims, idx, cfg, tech, space) + (
         "gate", n_exact, float(beta), float(tau))
     if cache_key not in _NSGA_CACHE:
-        n_imm = int(round(cfg.pop * cfg.immigrants))
-        imm_fn = jax.jit(jax.vmap(jax.vmap(
-            lambda k, nl, b: random_design(k, space, nl=nl, bounds=b),
-            in_axes=(0, None, None)),
-            in_axes=(0, None, None))) if n_imm else None
         body = _build_run_gated(space, dims, idx, cfg, tech, n_exact,
                                 float(beta), float(tau))
         _NSGA_CACHE[cache_key] = (
-            jax.jit(body), imm_fn, n_imm, dict(executed=False))
-    jitted, imm_fn, n_imm, state = _NSGA_CACHE[cache_key]
+            jax.jit(body), int(round(cfg.pop * cfg.immigrants)),
+            dict(executed=False))
+    jitted, n_imm, state = _NSGA_CACHE[cache_key]
 
     def runner(key, pop0, sur, arrays=None):
         # the exact make_nsga key chain: gating changes WHICH children
         # get exact evaluations, never which children are generated
         arr = {k: jnp.asarray(v) for k, v in (arrays or spec.arrays).items()}
         k_run, k_imm = jax.random.split(jnp.asarray(key))
-        imm = None
-        if n_imm:
-            kk = jax.random.split(k_imm, cfg.generations * n_imm)
-            nl = jnp.sum(arr["loopmask"], axis=1).astype(jnp.int32)
-            imm = imm_fn(kk.reshape(cfg.generations, n_imm, *kk.shape[1:]),
-                         nl, arr["bounds"])
+        imm = _immigrants(k_imm, space, cfg, n_imm, arr["loopmask"],
+                          arr["bounds"])
         out = jitted(k_run, pop0, arr, imm,
                      {k: jnp.asarray(v) for k, v in sur.items()})
         state["executed"] = True

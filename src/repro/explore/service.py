@@ -15,7 +15,8 @@ backend.  Four tricks make repeated / concurrent exploration cheap:
   ``cache_dir``).  A query whose budget is already covered by recorded
   evaluations is answered straight from the archive: no evaluator, no jit.
 * **Warm starts** — when compute IS needed, the initial population is
-  seeded from the cached front (topped up with ``random_design`` samples),
+  seeded from the cached front (topped up with ``random_design`` samples
+  drawn by one compiled ``nsga.sample_designs`` program),
   so follow-up queries with bigger budgets refine rather than restart.
 * **Adaptive budgets** (``BudgetPolicy``) — a query's budget is spent in
   quantized scan *segments*; after each segment the archive-projected
@@ -65,7 +66,7 @@ import numpy as np
 from .. import obs
 from ..core.constants import DEFAULT_TECH, TechConstants, tech_key
 from ..core.encoding import (DesignSpace, balanced_init, migrate,
-                             portable_signature, random_design, repair,
+                             portable_signature, repair,
                              space_digest)
 from ..core.evaluate import SystemSpec
 from ..core.optimizer import METRIC_KEYS
@@ -77,8 +78,9 @@ from .archive import (MANIFEST_NAME, ArchiveManifest, ConvergenceTrace,
                       spec_space_key)
 from . import quantize
 from .locks import LockTimeout, file_lock, lock_path
-from .nsga import (ISLAND_AXIS, NSGAConfig, _static_key, make_nsga,
-                   make_nsga_fused, make_nsga_gated)
+from .nsga import (ISLAND_AXIS, NSGAConfig, _static_key, design_template,
+                   make_nsga, make_nsga_fused, make_nsga_gated,
+                   sample_designs)
 from .surrogate import Surrogate, SurrogateConfig, fit_surrogate, harvest_rows
 
 # the default archive cache is anchored to the repo root (four levels above
@@ -490,9 +492,7 @@ class ExplorationService:
                 warnings.warn(f"discarding unreadable explore cache {p}: {e}")
                 p.unlink(missing_ok=True)
         if arc is None:
-            template = jax.tree.map(
-                np.asarray, random_design(jax.random.PRNGKey(0), space))
-            arc = ParetoArchive(self.capacity, template,
+            arc = ParetoArchive(self.capacity, design_template(space),
                                 n_obj=len(METRIC_KEYS),
                                 obj_keys=METRIC_KEYS)
         else:
@@ -976,13 +976,11 @@ class ExplorationService:
         with obs.span("explore.init_population", lanes=len(lanes)):
             for ln in lanes:
                 k_init, k_run = jax.random.split(ln["key"])
-                space = ln["g"]["space"]
                 ln.update(
                     k_run=k_run, trace=None, plateaued=False,
                     interrupted=False, spent_g=0, live=True,
                     st=ln["g"]["plateau"],
-                    filler=jax.vmap(lambda k: random_design(k, space))(
-                        jax.random.split(k_init, pop)))
+                    filler=sample_designs(k_init, ln["g"]["space"], pop))
         for s in range(n_seg):
             live = [ln for ln in lanes if ln["live"]]
             if not live:
@@ -1625,8 +1623,7 @@ class ExplorationService:
             return _seed_population(arc, pop, filler, extra)
 
         with obs.span("explore.init_population"):
-            filler = jax.vmap(lambda k: random_design(k, space))(
-                jax.random.split(k_init, pop))
+            filler = sample_designs(k_init, space, pop)
         st = plateau if plateau is not None else PlateauState()
         trace = None
         plateaued, interrupted, spent_g = False, False, 0

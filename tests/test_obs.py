@@ -540,21 +540,18 @@ def test_compiled_programs_carry_named_scopes():
     op metadata, each scope one path component."""
     import jax
     import jax.numpy as jnp
-    from repro.core.encoding import random_design
     from repro.explore.archive import _archive_update
-    from repro.explore.nsga import _NSGA_CACHE, make_nsga
+    from repro.explore.nsga import (_NSGA_CACHE, _immigrants, make_nsga,
+                                    sample_designs)
     p = _problem()
     cfg = NSGAConfig(pop=8, generations=2)
     make_nsga(p.spec, p.space, OBJ, cfg)
-    jitted, imm_fn, n_imm, _ = next(v for k, v in _NSGA_CACHE.items()
-                                    if cfg in k and k[-1] is None)
-    pop0 = jax.vmap(lambda k: random_design(k, p.space))(
-        jax.random.split(jax.random.PRNGKey(0), cfg.pop))
+    jitted, n_imm, _ = next(v for k, v in _NSGA_CACHE.items()
+                            if cfg in k and k[-1] is None)
+    pop0 = sample_designs(jax.random.PRNGKey(0), p.space, cfg.pop)
     arr = {k: jnp.asarray(v) for k, v in p.spec.arrays.items()}
-    kk = jax.random.split(jax.random.PRNGKey(1), cfg.generations * n_imm)
-    imm = imm_fn(kk.reshape(cfg.generations, n_imm, *kk.shape[1:]),
-                 jnp.sum(arr["loopmask"], axis=1).astype(jnp.int32),
-                 arr["bounds"])
+    imm = _immigrants(jax.random.PRNGKey(1), p.space, cfg, n_imm,
+                      arr["loopmask"], arr["bounds"])
     scan = _scopes(jitted.lower(jax.random.PRNGKey(2), pop0, arr,
                                 imm).as_text(debug_info=True))
     assert {"dataflow", "network", "energy_cost", "variation",
