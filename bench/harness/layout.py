@@ -7,8 +7,14 @@ reader.  Their files sit under ``bench/``:
     bench/configs/<config>.json     sizes, design space, objectives
     bench/traffic/<traffic>.json    entry point, budget, checked queries
     bench/metrics/<metric>.py       ``read(run) -> float | None``
+    bench/graphs/<builder>.py       the reference's workload graph of the
+                                    configurations with that ``builder``:
+                                    ``build(seq, **widths) -> (nests,
+                                    edges)``, optionally ``program_cfg``
+                                    (``reference.graph_module``)
 
-A later cell or metric is a new file; nothing here changes for it.
+A later cell, metric or graph builder is a new file; nothing here
+changes for it.
 """
 
 from __future__ import annotations
@@ -16,9 +22,18 @@ from __future__ import annotations
 import importlib.util
 import json
 from pathlib import Path
+from types import ModuleType
 from typing import Dict, List, Optional
 
 ROOT = Path(__file__).resolve().parents[2]
+
+
+def load_module(path: Path, name: str) -> ModuleType:
+    """The Python file at ``path``, executed as a module named ``name``."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 class Layout:
@@ -60,12 +75,8 @@ class Layout:
 
     def reader(self, metric: str):
         """The ``read`` function of ``bench/metrics/<metric>.py``."""
-        path = self.bench / "metrics" / f"{metric}.py"
-        spec = importlib.util.spec_from_file_location(
-            f"bench_metric_{metric.replace('.', '_')}", path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.read
+        return load_module(self.bench / "metrics" / f"{metric}.py",
+                           f"bench_metric_{metric.replace('.', '_')}").read
 
     def read_metric(self, metric: str, run) -> Optional[float]:
         return self.reader(metric)(run)

@@ -4,9 +4,11 @@ against: Monad's analytical performance, energy, area and cost model
 numpy, one design at a time.
 
 It imports nothing of the system under test.  Workload graphs are built
-here from a configuration's published widths, the padded loop-nest
-encoding and the routing tables are derived here, and the technology
-constants are the documented defaults, copied.  ``dtype`` selects the
+from a configuration's published widths by ``bench/graphs/<builder>.py``
+(found by the configuration's builder name) out of the ``Tensor``,
+``Loopnest`` and ``matmul`` defined here; the padded loop-nest encoding
+and the routing tables are derived here, and the technology constants
+are the documented defaults, copied.  ``dtype`` selects the
 floating type of every real-valued quantity: float64 for the reference,
 and a lower precision (``ml_dtypes.bfloat16``) for the control that a
 sound comparison has to reject.
@@ -25,9 +27,13 @@ from __future__ import annotations
 
 import dataclasses
 import math
+from pathlib import Path
+from types import ModuleType
 from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
+
+from .layout import ROOT, load_module
 
 MAX_LOOPS, MAX_TENSORS, MAX_DIMS = 8, 4, 4
 MAX_NODES = 36
@@ -86,31 +92,27 @@ def matmul(m: int, n: int, k: int) -> Loopnest:
                      Tensor("C", (("i",), ("j",)), True)))
 
 
-def attention_block(d_model, head_dim, n_heads, n_kv_heads, seq):
-    """QKV projection -> QK^T -> scores x V -> output projection, one head
-    wide for the score matmuls; edges carry each output into the next
-    matmul's left operand."""
-    qkv = (n_heads + 2 * n_kv_heads) * head_dim
-    nests = [matmul(seq, qkv, d_model), matmul(seq, seq, head_dim),
-             matmul(seq, head_dim, seq), matmul(seq, d_model,
-                                                n_heads * head_dim)]
-    return nests, [(0, 1, "C", "A"), (1, 2, "C", "A"), (2, 3, "C", "A")]
+GRAPHS_DIR = ROOT / "bench" / "graphs"
+_GRAPH_MODULES: Dict[Path, ModuleType] = {}
 
 
-def mlp_stack(d_model, d_ff, seq):
-    """Gate and up projections feeding the down projection."""
-    nests = [matmul(seq, d_ff, d_model), matmul(seq, d_ff, d_model),
-             matmul(seq, d_model, d_ff)]
-    return nests, [(0, 2, "C", "A"), (1, 2, "C", "B")]
+def graph_module(builder: str, graphs_dir: Path = GRAPHS_DIR) -> ModuleType:
+    """The module of ``<graphs_dir>/<builder>.py``, loaded once: its
+    ``build(seq, **widths) -> (nests, edges)`` and, where the library's
+    builder reads other attributes than the widths, ``program_cfg``."""
+    path = Path(graphs_dir) / f"{builder}.py"
+    if path not in _GRAPH_MODULES:
+        if not path.is_file():
+            raise KeyError(f"no reference graph for builder {builder!r}: "
+                           f"{path} does not exist")
+        _GRAPH_MODULES[path] = load_module(path, f"bench_graph_{builder}")
+    return _GRAPH_MODULES[path]
 
 
-GRAPHS = dict(attention_block=attention_block, mlp_stack=mlp_stack)
-
-
-def build_graph(graph: Dict, seq: int):
+def build_graph(graph: Dict, seq: int, graphs_dir: Path = GRAPHS_DIR):
     """(nests, edges) of a configuration's ``graph`` entry at ``seq``."""
     kw = {k: v for k, v in graph.items() if k != "builder"}
-    return GRAPHS[graph["builder"]](seq=seq, **kw)
+    return graph_module(graph["builder"], graphs_dir).build(seq=seq, **kw)
 
 
 def _nest_arrays(w: Loopnest) -> Dict[str, np.ndarray]:

@@ -18,18 +18,21 @@ import types
 from pathlib import Path
 from typing import Dict, List, Optional
 
+from .reference import graph_module
+
 JOB_TIMEOUT_S = 300.0       # a job not served by then counts as failed
 
 
 def program_graph(config: Dict, seq: int):
     """The configuration's workload graph, built by the system's own
-    graph builders at the configuration's widths."""
+    graph builder at the configuration's widths (mapped to the builder's
+    attributes by the graph file's ``program_cfg``, where it has one)."""
     from repro.core import presets
     g = dict(config["graph"])
     builder = g.pop("builder")
-    if builder == "mlp_stack":      # one dense expert of width d_ff
-        g = dict(d_model=g["d_model"], d_ff=g["d_ff"], n_experts=0,
-                 expert_ff=g["d_ff"])
+    to_cfg = getattr(graph_module(builder), "program_cfg", None)
+    if to_cfg is not None:
+        g = to_cfg(g)
     return getattr(presets, builder)(types.SimpleNamespace(**g), seq=seq)
 
 
