@@ -72,8 +72,20 @@ def _rand_perm_rows(key, W, levels, L):
     return perms.reshape(W, levels, L).astype(jnp.int32)
 
 
+def _fit_pe_budget(shape, budget: int):
+    """``shape`` with the PE-array and core-grid dims of every workload
+    scaled by one factor (rounded down, at least 1), so that a draw over
+    the PE budget lands near or under it; a draw within it is kept."""
+    pes = jnp.sum(jnp.prod(shape, axis=1)).astype(jnp.float32)
+    f = jnp.minimum(1.0, (budget / pes) ** 0.25)
+    fit = jnp.maximum(1.0, jnp.floor(shape[:, :4] * f)).astype(shape.dtype)
+    return shape.at[:, :4].set(fit)
+
+
 def random_design(key, space: DesignSpace, nl=None, bounds=None) -> Dict:
-    """Uniform random design point (the paper's 'Random' baseline).
+    """Uniform random design point (the paper's 'Random' baseline); under
+    a PE budget, a draw over it is scaled down to fit (``_fit_pe_budget``):
+    with many workloads nearly every uniform draw exceeds the budget.
 
     ``nl``/``bounds`` may be passed as traced arrays (from the workload
     arrays) so the compiled sampler is workload-independent — same
@@ -82,6 +94,8 @@ def random_design(key, space: DesignSpace, nl=None, bounds=None) -> Dict:
     ks = jax.random.split(key, 10)
     mx = jnp.asarray(space.max_shape, jnp.int32)
     shape = jax.random.randint(ks[0], (W, 6), 1, mx + 1)
+    if space.max_total_pes > 0:
+        shape = _fit_pe_budget(shape, space.max_total_pes)
     nl = jnp.asarray(space.n_loops if nl is None else nl)
     spatial = jax.random.randint(ks[1], (W, 6), 0, jnp.maximum(nl, 1)[:, None])
     order = _rand_perm_rows(ks[2], W, 3, L)

@@ -11,12 +11,21 @@
   ``repro.configs`` architectures* (attention blocks, MLP stacks, conv
   chains, scan-style contraction chains): the scenario-diverse library the
   cross-spec transfer subsystem is exercised on.
+* graph builders of one layer of a model configuration, read by attribute
+  from ``cfg``: ``attention_block``, ``mlp_stack``, ``conv_frontend``,
+  ``scan_chain``, ``hybrid_block``, and ``moe_layer`` (one expert-parallel
+  rank of a group-limited mixture-of-experts layer).
 """
 
 from __future__ import annotations
 
 import os
-from typing import Dict
+import threading
+from typing import Dict, Tuple
+
+import numpy as np
+
+from repro import obs
 
 from .constants import DEFAULT_TECH, TechConstants
 from .workload import (Edge, Workload, WorkloadGraph, contraction, conv2d,
@@ -171,6 +180,127 @@ def hybrid_block(cfg, seq: int = 256) -> WorkloadGraph:
     edges = [Edge(0, 1, "C", "A"), Edge(1, 3, "C", "A"),
              Edge(2, 3, "O", "B")]
     return WorkloadGraph(wls, edges)
+
+
+ROUTE_BLOCK = 4096      # tokens per rank the routing stream holds at least
+
+
+def noaux_tc_route(logits: np.ndarray, top_k: int, n_group: int,
+                   topk_group: int) -> np.ndarray:
+    """Expert choice of each token (row) under DeepSeek-V3's ``noaux_tc``
+    rule with a zero correction bias: sigmoid scores; a group's score is
+    the sum of its two best; the ``topk_group`` best groups are kept and
+    the ``top_k`` best experts within them chosen; ties go to the lower
+    index.  A (tokens, experts) bool mask with ``top_k`` per row."""
+    n, n_exp = logits.shape
+    scores = 1.0 / (1.0 + np.exp(-logits))
+    grouped = scores.reshape(n, n_group, n_exp // n_group)
+    group_score = np.partition(grouped, -2, axis=-1)[..., -2:].sum(-1)
+    kept = np.argsort(-group_score, axis=-1, kind="stable")[:, :topk_group]
+    keep = np.zeros((n, n_group), bool)
+    np.put_along_axis(keep, kept, True, axis=-1)
+    masked = np.where(np.repeat(keep, n_exp // n_group, axis=-1), scores,
+                      -np.inf)
+    kth = -np.partition(-masked, top_k - 1, axis=-1)[:, top_k - 1:top_k]
+    above = masked > kth
+    tied = masked == kth
+    room = top_k - above.sum(-1, keepdims=True)
+    return above | (tied & (np.cumsum(tied, axis=-1) <= room))
+
+
+class _RouteStream:
+    """The routing choices of the seeded token stream of one layer: token
+    ``t``'s logits are row ``t`` of ``default_rng(seed).standard_normal((N,
+    n_experts))`` for any ``N > t``; the stream grows by drawing further
+    rows from the same generator."""
+
+    def __init__(self, seed: int, n_experts: int, top_k: int, n_group: int,
+                 topk_group: int):
+        self.rng = np.random.default_rng(seed)
+        self.route = (top_k, n_group, topk_group)
+        self.n = 0                              # tokens drawn
+        # per expert, the tokens that pick it, ascending
+        self.tokens = [np.empty(0, np.int64)] * n_experts
+
+    def extend(self, n: int) -> None:
+        with obs.span("presets.moe_route_draw", tokens=n - self.n):
+            obs.inc("presets.moe_layer.stream_draws")
+            picks = noaux_tc_route(self.rng.standard_normal(
+                (n - self.n, len(self.tokens))), *self.route)
+            expert, token = np.nonzero(picks.T)
+            cut = np.searchsorted(expert, np.arange(len(self.tokens) + 1))
+            self.tokens = [np.concatenate([t, token[a:b] + self.n])
+                           for t, a, b in zip(self.tokens, cut, cut[1:])]
+            self.n = n
+
+    def counts(self, experts: range, n: int) -> np.ndarray:
+        """Tokens among the first ``n`` that pick each of ``experts``."""
+        return np.asarray([np.searchsorted(self.tokens[e], n)
+                           for e in experts], np.int64)
+
+
+_ROUTE_STREAMS: Dict[Tuple, _RouteStream] = {}
+_ROUTE_LOCK = threading.Lock()
+
+
+def routed_tokens(cfg, seq: int) -> np.ndarray:
+    """Tokens each expert ``cfg`` holds gets from the first
+    ``ep_size * seq`` tokens of the layer's routing stream.  The stream is
+    drawn once per widths and seed and kept, at least ``ROUTE_BLOCK``
+    tokens a rank, doubling when a longer ``seq`` needs more."""
+    if cfg.topk_method != "noaux_tc" or cfg.scoring_func != "sigmoid":
+        raise ValueError(f"moe_layer routes by noaux_tc over sigmoid scores,"
+                         f" not {cfg.topk_method} over {cfg.scoring_func}")
+    held = cfg.n_routed_experts // cfg.ep_size
+    key = (cfg.route_seed, cfg.n_routed_experts, cfg.num_experts_per_tok,
+           cfg.n_group, cfg.topk_group)
+    n = cfg.ep_size * seq
+    with _ROUTE_LOCK:
+        stream = _ROUTE_STREAMS.get(key)
+        if stream is None:
+            stream = _ROUTE_STREAMS[key] = _RouteStream(*key)
+        if stream.n < n:
+            per_rank = ROUTE_BLOCK
+            while per_rank < seq:
+                per_rank *= 2
+            stream.extend(cfg.ep_size * per_rank)
+        return stream.counts(range(cfg.ep_rank * held,
+                                   (cfg.ep_rank + 1) * held), n)
+
+
+def moe_layer(cfg, seq: int = 256) -> WorkloadGraph:
+    """One expert-parallel rank of a DeepSeek-V3-style MoE layer: the
+    router (``hidden_size -> n_routed_experts``) and the shared experts,
+    one gated MLP of width ``n_shared_experts * moe_intermediate_size``,
+    over the rank's ``seq`` tokens; then gate, up and down of each of the
+    ``n_routed_experts // ep_size`` experts the rank ``ep_rank`` holds,
+    each over the tokens the group-limited routing (``noaux_tc_route``)
+    sends it from all ``ep_size`` ranks' tokens (``routed_tokens``; an
+    expert no token picks gets one).  The router feeds every routed gate
+    and up, the dispatch; gate and up feed their down as in
+    ``mlp_stack``.  Nothing stands in for the other ranks."""
+    with obs.span("presets.moe_layer", seq=seq) as sp:
+        obs.inc("presets.moe_layer.calls")
+        d, ff = cfg.hidden_size, cfg.moe_intermediate_size
+        shared = cfg.n_shared_experts * ff
+        tokens = routed_tokens(cfg, seq)
+        wls = [matmul("router", seq, cfg.n_routed_experts, d),
+               matmul("shared_gate", seq, shared, d),
+               matmul("shared_up", seq, shared, d),
+               matmul("shared_down", seq, d, shared)]
+        edges = [Edge(1, 3, "C", "A"), Edge(2, 3, "C", "B")]
+        first = cfg.ep_rank * len(tokens)
+        for e, t in enumerate(tokens, start=first):
+            g, t = len(wls), max(int(t), 1)
+            wls += [matmul(f"expert{e}_gate", t, ff, d),
+                    matmul(f"expert{e}_up", t, ff, d),
+                    matmul(f"expert{e}_down", t, d, ff)]
+            edges += [Edge(0, g, "C", "A"), Edge(0, g + 1, "C", "A"),
+                      Edge(g, g + 2, "C", "A"), Edge(g + 1, g + 2, "C", "B")]
+        sp.set(workloads=len(wls), edges=len(edges),
+               tokens_here=int(tokens.sum()), tokens_max=int(tokens.max()),
+               tokens_min=int(tokens.min()))
+        return WorkloadGraph(wls, edges)
 
 
 def workload_library() -> Dict[str, WorkloadGraph]:

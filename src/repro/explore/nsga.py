@@ -116,7 +116,8 @@ def _sampler_key(space: DesignSpace):
     as runtime arrays — and nothing else: no ``space.spec`` content, so
     problems that differ only in their workload share one program."""
     return (space.W, space.CH, space.max_shape, space.max_logB,
-            space.fixed_packaging, space.fixed_family, space.allow_pipeline)
+            space.max_total_pes, space.fixed_packaging, space.fixed_family,
+            space.allow_pipeline)
 
 
 def sample_designs(key, space: DesignSpace, n, nl=None, bounds=None) -> Dict:
