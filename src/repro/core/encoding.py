@@ -31,7 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .evaluate import SystemSpec
-from .network import MAX_NODES, N_FAMILIES
+from .network import MAX_NODES, N_FAMILIES, N_TOT
 from .workload import (MAX_LOOPS, graph_feature_rows, workload_signature)
 
 
@@ -64,6 +64,16 @@ class DesignSpace:
 
     def max_nodes(self) -> int:
         return min(MAX_NODES, self.W * self.CH)
+
+    @property
+    def node_bound(self) -> int:
+        """Static bound on the network node ids any design of the space
+        produces, feasible or not (so not ``max_nodes``): placement ids
+        lie below ``W*CH``, and the DRAM node is ``n_nodes``, at most
+        ``W`` times the largest chiplet array.  The scan's network model
+        is sized by it (``evaluate_arrays(node_bound=...)``)."""
+        ms = self.max_shape
+        return min(N_TOT, self.W * max(self.CH, ms[4] * ms[5]) + 1)
 
 
 def _rand_perm_rows(key, W, levels, L):

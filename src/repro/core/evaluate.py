@@ -103,10 +103,17 @@ def evaluate_system(spec: SystemSpec, design: Dict,
 
 
 def evaluate_arrays(arrays: Dict, design: Dict, dims: Tuple[int, int, int],
-                    tech: TechConstants = DEFAULT_TECH) -> Dict:
+                    tech: TechConstants = DEFAULT_TECH,
+                    node_bound: int = N_TOT) -> Dict:
     """Same as ``evaluate_system`` but over raw (traced) workload arrays, so
     one jit compilation is shared by every workload graph with equal padded
-    dims (W, CH, E) — the whole Fig.-7 suite compiles once."""
+    dims (W, CH, E) — the whole Fig.-7 suite compiles once.
+
+    ``node_bound`` (static, <= ``N_TOT``) sizes the network model: the
+    next-hop table, hop walk and link loads cover node ids below it.  It
+    must exceed every node id a design can produce — its placement
+    entries and the DRAM node ``n_nodes`` — which
+    ``DesignSpace.node_bound`` guarantees for the designs of a space."""
     arr = {k: jnp.asarray(v) for k, v in arrays.items()}
     W, CH, E = dims
     L = MAX_LOOPS
@@ -190,7 +197,8 @@ def evaluate_arrays(arrays: Dict, design: Dict, dims: Tuple[int, int, int],
         bwr = vol / jnp.maximum(d_min, 1.0)
 
         # ---- network: provision at hotspot, cap by packaging ----------------
-        nh_all = jnp.asarray(next_hop_tables())
+        nh_all = jnp.asarray(
+            next_hop_tables()[:, :node_bound, :node_bound])
         tcode = (design["family"] * (MAX_NODES + 1)
                  + jnp.clip(n_nodes, 1, MAX_NODES))
         nh = nh_all[tcode]

@@ -37,7 +37,6 @@ import numpy as np
 
 MAX_NODES = 36          # paper Sec. IV-C: placement field is up to 36 nodes
 N_TOT = MAX_NODES + 1   # + DRAM node
-MAX_HOPS = 40           # >= diameter of any supported topology (chain-36 + DRAM)
 FAM_CHAIN, FAM_RING, FAM_MESH, FAM_STAR = 0, 1, 2, 3
 N_FAMILIES = 4
 
@@ -120,14 +119,20 @@ def topo_code(family: int, n_nodes: int) -> int:
 # jnp contention evaluation
 # ---------------------------------------------------------------------------
 def route_links(nh, src, dst):
-    """Walk paths for all flows.  nh: (N_TOT,N_TOT) int; src/dst: (F,) int.
-    Returns (links, hops): links (MAX_HOPS, F, 2) int32 with (u,v) per hop
-    (u==v once arrived => no link), hops (F,) float."""
+    """Walk paths for all flows.  nh: (K, K) int next-hop table, K <= N_TOT
+    a static node bound (every node id in ``src``/``dst`` and every next
+    hop is below K); src/dst: (F,) int.
+    Returns (links, hops): links (max(K-1, 1), F, 2) int32 with (u,v) per
+    hop (u==v once arrived => no link), hops (F,) float.
+
+    K-1 steps suffice: a path among n chiplet nodes plus DRAM has at most
+    n hops in every family, and an id with no node in the table reaches
+    its target in one."""
     def step(cur, _):
         nxt = nh[cur, dst].astype(jnp.int32)
         return nxt, jnp.stack([cur, nxt], axis=-1)
     _, links = jax.lax.scan(step, src.astype(jnp.int32), None,
-                            length=MAX_HOPS)
+                            length=max(nh.shape[0] - 1, 1))
     hops = jnp.sum(links[:, :, 0] != links[:, :, 1], axis=0).astype(jnp.float32)
     return links, hops
 
@@ -136,7 +141,8 @@ def evaluate_network(nh, src, dst, bwr, vol_bytes, fmask,
                      link_bw, dram_bw, router_delay_ns, n_nodes):
     """Contention-aware per-flow delay (paper Sec. III-C last equation).
 
-    nh:        (N_TOT, N_TOT) next-hop table (jnp int)
+    nh:        (K, K) next-hop table (jnp int), K <= N_TOT the static node
+               bound of ``route_links``; links are ids u*K + v
     src, dst:  (F,) node ids per flow (DRAM node = n_nodes)
     bwr:       (F,) bandwidth requirement GB/s
     vol_bytes: (F,) transfer volume
@@ -149,9 +155,10 @@ def evaluate_network(nh, src, dst, bwr, vol_bytes, fmask,
     u = links[:, :, 0].astype(jnp.int32)        # (H, F)
     v = links[:, :, 1].astype(jnp.int32)
     active = (u != v) & fmask[None, :]
-    lid = u * N_TOT + v                          # directed link id
+    k = nh.shape[0]
+    lid = u * k + v                              # directed link id
 
-    load = jnp.zeros((N_TOT * N_TOT,), Fd)
+    load = jnp.zeros((k * k,), Fd)
     load = load.at[lid.reshape(-1)].add(
         jnp.where(active, bwr[None, :], 0.0).reshape(-1))
     hotspot = jnp.max(load)

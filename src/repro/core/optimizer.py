@@ -129,7 +129,8 @@ def make_sa(spec: SystemSpec, space: DesignSpace,
         return runner
 
     def obj(design, weights, arr):
-        m = evaluate_arrays(arr, design, dims, tech)
+        m = evaluate_arrays(arr, design, dims, tech,
+                            node_bound=space.node_bound)
         return objective_from_metrics(space, design, m, weights)
 
     def chain(key, d0, weights, arr):

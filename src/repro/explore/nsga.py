@@ -45,6 +45,7 @@ from .. import obs
 from ..core.encoding import (ALL_FIELDS, DesignSpace, feasibility_penalty,
                              mutate, random_design)
 from ..core.evaluate import SystemSpec, evaluate_arrays
+from ..core.network import N_TOT
 from ..core.optimizer import METRIC_KEYS, log_metric_stack, metric_stack
 from .archive import (BIG, HV_LOG_REF, crowding_distance, dominance_counts,
                       flatten_design, hypervolume_2d_jit, objective_pairs)
@@ -179,6 +180,18 @@ def _static_key(dims, idx, cfg, tech, space):
     return (dims, idx, cfg, tech, space.max_shape, space.max_logB,
             space.max_total_pes, space.fixed_packaging,
             space.fixed_family, space.allow_pipeline)
+
+
+def _node_bound(space):
+    """The static node bound a scan program being built is sized by
+    (``DesignSpace.node_bound``; ``_static_key`` already holds what it is
+    derived from), recorded as the ``network.node_bound`` gauge; programs
+    below the full ``N_TOT`` nodes count as ``network.bounded_programs``."""
+    k = space.node_bound
+    obs.gauge("network.node_bound", k)
+    if k < N_TOT:
+        obs.inc("network.bounded_programs")
+    return k
 
 
 def _immigrants(k_imm, space, cfg, n_imm, loopmask, bounds):
@@ -438,9 +451,10 @@ def _build_run_gated(space, dims, idx, cfg, tech, n_exact: int,
     obj_idx = jnp.asarray(idx, jnp.int32)
     pairs = objective_pairs(len(idx))
     hv_ref = jnp.asarray([HV_LOG_REF, HV_LOG_REF], F)
+    k_nodes = _node_bound(space)
 
     def eval_one(d, arr):
-        m = evaluate_arrays(arr, d, dims, tech)
+        m = evaluate_arrays(arr, d, dims, tech, node_bound=k_nodes)
         with jax.named_scope("selection"):
             raw = metric_stack(m)
             p = feasibility_penalty(space, d, m)
@@ -592,9 +606,10 @@ def _build_run(space, dims, idx, cfg, tech, n_isl: int = 1):
     obj_idx = jnp.asarray(idx, jnp.int32)
     pairs = objective_pairs(len(idx))
     hv_ref = jnp.asarray([HV_LOG_REF, HV_LOG_REF], F)
+    k_nodes = _node_bound(space)
 
     def eval_one(d, arr):
-        m = evaluate_arrays(arr, d, dims, tech)
+        m = evaluate_arrays(arr, d, dims, tech, node_bound=k_nodes)
         with jax.named_scope("selection"):
             # the penalized log-objectives selection ranks on
             raw = metric_stack(m)

@@ -84,10 +84,118 @@ def test_routing_tables_reach_destination():
             for s in range(n):
                 for d in list(range(n)) + [n]:       # incl. DRAM node
                     cur, hops = s, 0
-                    while cur != d and hops < network.MAX_HOPS:
+                    while cur != d and hops < network.N_TOT:
                         cur = int(nh[cur, d])
                         hops += 1
                     assert cur == d, (fam, n, s, d)
+
+
+@pytest.mark.parametrize("fam", range(network.N_FAMILIES))
+def test_routing_paths_bounded_by_node_count(fam):
+    """The hop walk's length rests on this: on the table of n chiplet
+    nodes plus DRAM, every path between any two ids of the full table
+    (unused slots included) arrives within n hops."""
+    nh_all = network.next_hop_tables()
+    ids = np.arange(network.N_TOT)
+    src, dst = np.meshgrid(ids, ids, indexing="ij")
+    for n in range(1, network.MAX_NODES + 1):
+        nh = nh_all[network.topo_code(fam, n)].astype(np.int64)
+        cur = src
+        for _ in range(n):
+            cur = nh[cur, dst]
+        assert (cur == dst).all(), (fam, n)
+        # and the table cut to any bound K > n never leaves [0, K)
+        for k in range(n + 1, network.N_TOT + 1):
+            assert nh[:k, :k].max() < k, (fam, n, k)
+
+
+def _bounded_space(name):
+    """Spaces whose designs sit on fewer than ``N_TOT`` nodes: the bench's
+    c1 (4 workloads, one 1x1 chiplet array each), a 28-workload expert
+    layer of the same kind, and ch_max 4 with 2x2 arrays (unused
+    placement slots, a design-dependent DRAM id)."""
+    import types
+    one = (16, 16, 4, 4, 1, 1)
+    if name == "c1":
+        g = C.presets.attention_block(types.SimpleNamespace(
+            d_model=64, head_dim=8, n_heads=4, n_kv_heads=2), seq=32)
+        return C.DesignSpace(C.SystemSpec.build(g, ch_max=1), max_shape=one,
+                             max_total_pes=4096)
+    if name == "moe_w28":
+        g = C.presets.moe_layer(types.SimpleNamespace(
+            hidden_size=16, moe_intermediate_size=8, n_routed_experts=32,
+            num_experts_per_tok=4, n_shared_experts=1, n_group=4,
+            topk_group=2, topk_method="noaux_tc", scoring_func="sigmoid",
+            ep_size=4, ep_rank=0, route_seed=11), 64)
+        return C.DesignSpace(C.SystemSpec.build(g, ch_max=1), max_shape=one,
+                             max_total_pes=4096)
+    g = C.presets.attention_block(types.SimpleNamespace(
+        d_model=64, head_dim=8, n_heads=4, n_kv_heads=2), seq=32)
+    return C.DesignSpace(C.SystemSpec.build(g, ch_max=4),
+                         max_shape=(8, 8, 2, 2, 2, 2))
+
+
+def test_node_bound_of_a_space():
+    assert _bounded_space("c1").node_bound == 5
+    assert _bounded_space("moe_w28").W == 28
+    assert _bounded_space("moe_w28").node_bound == 29
+    assert _bounded_space("ch_max4_2x2").node_bound == 17
+    # the default 6x6 chiplet arrays reach the full table
+    space = _bounded_space("c1")
+    assert C.DesignSpace(space.spec).node_bound == network.N_TOT
+
+
+@pytest.mark.parametrize("name", ["c1", "moe_w28", "ch_max4_2x2"])
+def test_node_bound_leaves_metrics_bit_identical(name):
+    """256 seeded random designs over all four families: the network
+    sized by the space's node bound gives the 37-node model's metrics
+    bit for bit (the bound drops only hops after arrival and links no
+    flow can use)."""
+    from repro.core.evaluate import evaluate_arrays
+    from repro.explore.nsga import sample_designs
+    space = _bounded_space(name)
+    k = space.node_bound
+    assert k < network.N_TOT
+    designs = sample_designs(jax.random.PRNGKey(1700000017), space, 256)
+    assert set(np.asarray(designs["family"]).tolist()) == set(
+        range(network.N_FAMILIES))
+    spec = space.spec
+    dims = (spec.W, spec.CH, spec.E)
+
+    def metrics(bound):
+        f = jax.jit(jax.vmap(lambda d, arr: evaluate_arrays(
+            arr, d, dims, node_bound=bound), in_axes=(0, None)))
+        return jax.device_get(f(designs, spec.arrays))
+
+    got, want = metrics(k), metrics(network.N_TOT)
+    assert set(got) == set(want)
+    for key in want:
+        np.testing.assert_array_equal(got[key], want[key], err_msg=key)
+
+
+@pytest.mark.parametrize("fam,n,src,dst", [
+    (network.FAM_MESH, 9, [0], [8]),
+    (network.FAM_CHAIN, 3, [0, 1], [2, 2])])
+def test_network_on_bounded_table_matches_full(fam, n, src, dst):
+    """``route_links`` and ``evaluate_network`` on the table cut to K =
+    n + 1 nodes walk K - 1 hops and give the full table's delays, hops
+    and hotspot."""
+    nh = network.next_hop_tables()[network.topo_code(fam, n)]
+    src, dst = jnp.asarray(src), jnp.asarray(dst)
+    k = n + 1
+    links, hops = network.route_links(jnp.asarray(nh[:k, :k]), src, dst)
+    links_full, hops_full = network.route_links(jnp.asarray(nh), src, dst)
+    assert links.shape[0] == k - 1
+    np.testing.assert_array_equal(hops, hops_full)
+    np.testing.assert_array_equal(links, links_full[:k - 1])
+    f = len(src)
+    args = (src, dst, jnp.full((f,), 32.0), jnp.full((f,), 3.2e4),
+            jnp.ones((f,), bool), 32.0, 128.0, 20.0, n)
+    cut = network.evaluate_network(jnp.asarray(nh[:k, :k]), *args)
+    full = network.evaluate_network(jnp.asarray(nh), *args)
+    assert set(cut) == set(full)
+    for key in full:
+        np.testing.assert_array_equal(cut[key], full[key], err_msg=key)
 
 
 def test_mesh_xy_hop_count():

@@ -292,6 +292,37 @@ def test_nsga_scans_out_convergence_trace():
     assert t.hypervolume[-1, 0] >= hv_host * (1 - 1e-4)
 
 
+def test_scan_program_records_its_node_bound(monkeypatch):
+    """Building a scan program records the node bound its network is
+    sized by; programs below the full table are counted, cache hits
+    build nothing."""
+    import types
+    from repro import obs
+    from repro.core.network import N_TOT
+    from repro.explore import nsga
+    monkeypatch.setattr(nsga, "_NSGA_CACHE", {})
+    obs.enable()
+    g = C.presets.attention_block(types.SimpleNamespace(
+        d_model=64, head_dim=8, n_heads=4, n_kv_heads=2), seq=32)
+    spec = C.SystemSpec.build(g, ch_max=1)
+    c1 = C.DesignSpace(spec, max_shape=(16, 16, 4, 4, 1, 1))
+    cfg = NSGAConfig(pop=8, generations=1)
+
+    def gauge():
+        return obs.REGISTRY.gauge("network.node_bound").value
+
+    def bounded():
+        return obs.REGISTRY.counter("network.bounded_programs").value
+
+    n0 = bounded()
+    make_nsga(spec, c1, cfg=cfg)
+    assert gauge() == 5 and bounded() - n0 == 1
+    make_nsga(spec, c1, cfg=cfg)
+    assert bounded() - n0 == 1
+    make_nsga(spec, C.DesignSpace(spec), cfg=cfg)
+    assert gauge() == N_TOT and bounded() - n0 == 1
+
+
 def test_pmx_always_yields_valid_permutations():
     """The placement crossover must keep children valid permutations for
     every cut-point draw and any parent pair."""
